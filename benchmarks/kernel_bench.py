@@ -1,9 +1,11 @@
-"""Kernel micro-bench: Pallas (interpret) vs jnp reference wall time on
-CPU + the *analytic* TPU projection from tile shapes.
+"""Kernel micro-bench: Pallas vs jnp reference wall time, with the
+*analytic* TPU projection from tile shapes.
 
-Interpret-mode wall times are NOT TPU performance — the value of this
-section is (a) correctness at benchmark shapes and (b) the VMEM/MXU
-roofline sanity of the chosen block shapes, printed per kernel.
+The kernels run as `repro.kernels.ops.interpret_mode` decides: compiled
+on a TPU, interpreted on the CPU backend.  Interpreted wall times are
+NOT TPU performance; there the value of this section is (a) correctness
+at benchmark shapes and (b) the VMEM/MXU roofline sanity of the chosen
+block shapes, printed per kernel.
 
 Observability (PR 7): each kernel's reference and Pallas timings run
 inside flight-recorder spans on a *wall-clock* tracer (the simulated
@@ -60,17 +62,16 @@ def main(quick: bool = False) -> dict:
                   lambda a, b, c: ref.flash_attention_ref(a, b, c), q, k, v)
     t_pal = timed("flash_attention", "pallas",
                   lambda a, b, c: ops.flash_attention(
-                      a, b, c, block_q=128, block_k=128, interpret=True),
+                      a, b, c, block_q=128, block_k=128),
                   q, k, v)
     err = float(jnp.abs(
-        ops.flash_attention(q, k, v, block_q=128, block_k=128,
-                            interpret=True)
+        ops.flash_attention(q, k, v, block_q=128, block_k=128)
         - ref.flash_attention_ref(q, k, v)).max())
     vmem_kib = (128 * D * 4 * 2 + 128 * D * 4 + 128 * 128 * 4) / 1024
-    rows["flash_attention"] = {"interp_us": t_pal * 1e6,
+    rows["flash_attention"] = {"pallas_us": t_pal * 1e6,
                                "ref_us": t_ref * 1e6, "max_err": err,
                                "tile_vmem_kib": vmem_kib}
-    print(f"flash_attention,{t_pal * 1e6:.0f},interp_us "
+    print(f"flash_attention,{t_pal * 1e6:.0f},pallas_us "
           f"ref_us={t_ref * 1e6:.0f} max_err={err:.1e} "
           f"tile_vmem={vmem_kib:.0f}KiB", flush=True)
 
@@ -80,15 +81,14 @@ def main(quick: bool = False) -> dict:
                   qd, k, v)
     t_pal = timed("decode_attention", "pallas",
                   lambda a, b, c: ops.decode_attention(
-                      a, b, c, jnp.int32(S), block_s=128, interpret=True),
+                      a, b, c, jnp.int32(S), block_s=128),
                   qd, k, v)
     err = float(jnp.abs(
-        ops.decode_attention(qd, k, v, jnp.int32(S), block_s=128,
-                             interpret=True)
+        ops.decode_attention(qd, k, v, jnp.int32(S), block_s=128)
         - ref.decode_attention_ref(qd, k, v, S)).max())
-    rows["decode_attention"] = {"interp_us": t_pal * 1e6,
+    rows["decode_attention"] = {"pallas_us": t_pal * 1e6,
                                 "ref_us": t_ref * 1e6, "max_err": err}
-    print(f"decode_attention,{t_pal * 1e6:.0f},interp_us "
+    print(f"decode_attention,{t_pal * 1e6:.0f},pallas_us "
           f"ref_us={t_ref * 1e6:.0f} max_err={err:.1e} "
           f"bw_bound=True", flush=True)
 
@@ -99,15 +99,14 @@ def main(quick: bool = False) -> dict:
     hits = jnp.asarray(rng.integers(0, 2, N), jnp.int8)
     t_pal = timed("ralt_update", "pallas",
                   lambda a, b, c: ops.ralt_update(
-                      a, b, c, 60, 0.5, interpret=True)[1],
+                      a, b, c, 60, 0.5)[1],
                   ticks, scores, hits)
-    nt, ns, _ = ops.ralt_update(ticks, scores, hits, 60, 0.5,
-                                interpret=True)
+    nt, ns, _ = ops.ralt_update(ticks, scores, hits, 60, 0.5)
     wt, ws = ref.ralt_update_ref(ticks, scores, hits, 60, 0.999)
     err = float(jnp.abs(ns - ws).max())
-    rows["ralt_update"] = {"interp_us": t_pal * 1e6, "n": N,
+    rows["ralt_update"] = {"pallas_us": t_pal * 1e6, "n": N,
                            "max_err": err}
-    print(f"ralt_update,{t_pal * 1e6:.0f},interp_us n={N} "
+    print(f"ralt_update,{t_pal * 1e6:.0f},pallas_us n={N} "
           f"max_err={err:.1e} fused_passes=1", flush=True)
 
     Bz, nC, Q, nh, hp, ns_ = 1, 4, 64, 2, 64, 64
@@ -118,15 +117,15 @@ def main(quick: bool = False) -> dict:
                                            (Bz, nC, Q, nh)))
     A = -jnp.exp(jax.random.normal(jax.random.key(8), (nh,)) * 0.1)
     t_pal = timed("ssd_scan", "pallas",
-                  lambda *a: ops.ssd_scan(*a, interpret=True)[0],
+                  lambda *a: ops.ssd_scan(*a)[0],
                   x, Bm, Cm, dt, A)
-    y, h = ops.ssd_scan(x, Bm, Cm, dt, A, interpret=True)
+    y, h = ops.ssd_scan(x, Bm, Cm, dt, A)
     wy, wh = ref.ssd_chunk_ref(x, Bm, Cm, dt, A,
                                jnp.zeros((Bz, nh, ns_, hp)))
     err = float(jnp.abs(y - wy).max())
-    rows["ssd_scan"] = {"interp_us": t_pal * 1e6, "max_err": err,
+    rows["ssd_scan"] = {"pallas_us": t_pal * 1e6, "max_err": err,
                         "state_vmem_kib": (ns_ * hp * 4) / 1024}
-    print(f"ssd_scan,{t_pal * 1e6:.0f},interp_us max_err={err:.1e} "
+    print(f"ssd_scan,{t_pal * 1e6:.0f},pallas_us max_err={err:.1e} "
           f"state_vmem={(ns_ * hp * 4) / 1024:.0f}KiB", flush=True)
 
     if trace_path:

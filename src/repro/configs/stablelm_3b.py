@@ -1,5 +1,5 @@
 """stablelm-3b [dense] — 32L d_model=2560 32H (GQA kv=32 => MHA)
-d_ff=6912 vocab=50304 [hf:stabilityai/stablelm-2-1_6b; unverified].
+d_ff=6912 vocab=50304 [hf:stabilityai/stablelm-3b-4e1t config.json].
 Pure full attention => long_500k skipped.
 """
 from ..models.config import Block, ModelConfig

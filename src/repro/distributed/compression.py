@@ -19,7 +19,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 F32 = jnp.float32
@@ -53,9 +52,9 @@ def compressed_allreduce(grads, error_state, mesh, dp_axes=("data",)):
     specs = jax.tree.map(lambda g: P(*([None] * g.ndim)), grads)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(specs, specs), out_specs=(specs, specs),
-        check_rep=False)
+        check_vma=False)
     def run(g, e):
         flat_g, tdef = jax.tree.flatten(g)
         flat_e = tdef.flatten_up_to(e)

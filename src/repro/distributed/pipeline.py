@@ -18,7 +18,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -38,9 +37,9 @@ def gpipe_apply(stage_fn, stage_params, x_micro, *, mesh,
     pspecs = jax.tree.map(lambda _: P(axis), stage_params)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(pspecs, P()), out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     def run(params_local, xs):
         sid = jax.lax.axis_index(axis)
         local = jax.tree.map(lambda p: p[0], params_local)

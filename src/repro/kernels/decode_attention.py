@@ -66,7 +66,7 @@ def _decode_kernel(vl_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def decode_attention(q, k_cache, v_cache, valid_len, *,
-                     block_s: int = 512, interpret: bool | None = None):
+                     block_s: int = 512, interpret: bool):
     """q: (B, H, D); caches: (B, S, KVH, D); valid_len: scalar int32.
     -> (B, H, D)."""
     B, H, D = q.shape
@@ -75,8 +75,6 @@ def decode_attention(q, k_cache, v_cache, valid_len, *,
     block_s = min(block_s, S)
     assert S % block_s == 0, (S, block_s)
     n_s = S // block_s
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
 
     qg = q.reshape(B, KVH, G, D)
     kh = jnp.swapaxes(k_cache, 1, 2)       # (B, KVH, S, D)

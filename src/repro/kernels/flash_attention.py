@@ -86,7 +86,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: int | None = None, block_q: int = 512,
                     block_k: int = 512, kv_len: int | None = None,
-                    interpret: bool | None = None):
+                    interpret: bool):
     """q: (B, Sq, H, D); k/v: (B, Skv, KVH, D) -> (B, Sq, H, D)."""
     B, Sq, H, D = q.shape
     _, Skv, KVH, _ = k.shape
@@ -96,8 +96,6 @@ def flash_attention(q, k, v, *, causal: bool = True,
     block_k = min(block_k, Skv)
     assert Sq % block_q == 0 and Skv % block_k == 0, (Sq, Skv)
     n_q, n_kv = Sq // block_q, Skv // block_k
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
 
     # head-major for tiling
     qh = jnp.swapaxes(q, 1, 2)       # (B, H, Sq, D)

@@ -1,9 +1,10 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-Each op auto-selects interpret mode off-TPU (the kernel body executes
-in Python on CPU for correctness validation) and compiles the real
-Mosaic kernel on TPU.  `ref.py` holds the pure-jnp oracles; tests sweep
-shapes/dtypes asserting allclose between the two.
+`interpret_mode` is the one place that decides how a kernel runs: the
+real Mosaic kernel on a TPU, the Pallas interpreter on the CPU backend
+(correctness checks in tests), and an error on any other backend, so a
+kernel never falls back silently.  `ref.py` holds the pure-jnp oracles;
+tests sweep shapes/dtypes asserting allclose between the two.
 """
 from __future__ import annotations
 
@@ -17,33 +18,30 @@ from .ralt_score import ralt_update as _ralt_update
 from .ssd_scan import ssd_scan as _ssd_scan
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window",
-                                             "block_q", "block_k",
-                                             "interpret"))
-def flash_attention(q, k, v, *, causal: bool = True, window=None,
-                    block_q: int = 512, block_k: int = 512,
-                    interpret=None):
-    return _flash_attention(q, k, v, causal=causal, window=window,
-                            block_q=block_q, block_k=block_k,
-                            interpret=interpret)
+def interpret_mode() -> bool:
+    """True on the CPU backend, False on a TPU; other backends raise."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"Pallas kernels run on a TPU (or interpreted on "
+                       f"the CPU backend), not on {backend!r}")
 
 
-@functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
-def decode_attention(q, k_cache, v_cache, valid_len, *,
-                     block_s: int = 512, interpret=None):
-    return _decode_attention(q, k_cache, v_cache, valid_len,
-                             block_s=block_s, interpret=interpret)
+def _on_backend(kernel, *static_argnames):
+    """`kernel` jitted with its static arguments, run as `interpret_mode`
+    decides."""
+    jitted = jax.jit(kernel, static_argnames=(*static_argnames, "interpret"))
+
+    @functools.wraps(kernel)
+    def op(*args, **kwargs):
+        return jitted(*args, interpret=interpret_mode(), **kwargs)
+    return op
 
 
-@functools.partial(jax.jit, static_argnames=("alpha", "block_n",
-                                             "interpret"))
-def ralt_update(ticks, scores, hits, now, threshold, *,
-                alpha: float = 0.999, block_n: int = 1024,
-                interpret=None):
-    return _ralt_update(ticks, scores, hits, now, threshold, alpha,
-                        block_n=block_n, interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def ssd_scan(x, Bm, Cm, dt, A, *, interpret=None):
-    return _ssd_scan(x, Bm, Cm, dt, A, interpret=interpret)
+flash_attention = _on_backend(_flash_attention, "causal", "window",
+                              "block_q", "block_k", "kv_len")
+decode_attention = _on_backend(_decode_attention, "block_s")
+ralt_update = _on_backend(_ralt_update, "alpha", "block_n")
+ssd_scan = _on_backend(_ssd_scan)

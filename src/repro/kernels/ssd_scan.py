@@ -69,14 +69,12 @@ def _ssd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, y_ref, hfin_ref,
         hfin_ref[0, 0] = h_new
 
 
-def ssd_scan(x, Bm, Cm, dt, A, *, interpret: bool | None = None):
+def ssd_scan(x, Bm, Cm, dt, A, *, interpret: bool):
     """x: (B, nC, Q, nh, hp); Bm/Cm: (B, nC, Q, ns); dt: (B, nC, Q, nh);
     A: (nh,) negative decay rates.  h0 = 0.
     Returns (y like x, h_final (B, nh, ns, hp))."""
     Bsz, nC, Q, nh, hp = x.shape
     ns = Bm.shape[-1]
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
 
     # head-major layouts for clean tiling
     xh = jnp.transpose(x, (0, 3, 1, 2, 4)).reshape(Bsz, nh, nC * Q, hp)

@@ -10,19 +10,21 @@ so scaling 2 -> N pods changes a single mesh dimension.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(shape))
 
 
 def make_debug_mesh(n_devices: int | None = None, model: int = 2):
     """Small mesh over whatever devices exist (tests, CI)."""
     n = n_devices or len(jax.devices())
     model = min(model, n)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         (AxisType.Auto,) * 2)
 
 
 def axis_binding(mesh, *, shape_kind: str = "train",

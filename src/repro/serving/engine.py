@@ -19,6 +19,14 @@ import numpy as np
 from ..models.transformer import decode_step, init_cache, init_params
 from ..obs.serving import NULL_SERVING_OBS
 
+# The parameters are an argument, never closed over: JAX embeds a
+# closed-over array in the program as a constant (gigabytes at published
+# widths).  The cache is donated, so each step and each wave's reset
+# update it in place instead of holding a second copy.
+_decode = jax.jit(decode_step, static_argnums=1, donate_argnums=2)
+_zeroed = jax.jit(lambda cache: jax.tree.map(jnp.zeros_like, cache),
+                  donate_argnums=0, keep_unused=True)
+
 
 @dataclasses.dataclass
 class Request:
@@ -42,8 +50,6 @@ class ServeEngine:
         self.params = params if params is not None else init_params(
             jax.random.key(seed), cfg)
         self.cache = init_cache(cfg, batch, max_len)
-        self._step = jax.jit(
-            lambda c, t, p: decode_step(self.params, cfg, c, t, p))
         self.slots: list = [None] * batch
         self.pos = 0                    # shared position (lockstep)
         self.queue: list = []
@@ -70,14 +76,20 @@ class ServeEngine:
         state = dict(self.__dict__)
         state.pop("_obs", None)
         state.pop("_obs_track", None)
-        state.pop("_step", None)        # jitted closure: rebuilt on load
         return state
 
-    def __setstate__(self, state):
-        self.__dict__.update(state)
-        cfg = self.cfg
-        self._step = jax.jit(
-            lambda c, t, p: decode_step(self.params, cfg, c, t, p))
+    def reset(self):
+        """Zero the decode cache in place (start of a wave)."""
+        self.cache = _zeroed(self.cache)
+
+    def step(self, tokens, pos):
+        """One decode step for every slot: tokens (batch,) int32 at the
+        shared position `pos`.  Returns the (batch, padded vocab)
+        logits."""
+        logits, self.cache = _decode(self.params, self.cfg, self.cache,
+                                     jnp.asarray(tokens, jnp.int32),
+                                     jnp.int32(pos))
+        return logits
 
     def run(self, max_steps: int = 10_000):
         """Lockstep loop: all live slots share the position counter
@@ -102,7 +114,7 @@ class ServeEngine:
                                     "queued": len(self.queue)})
             wave_prompt = max(len(r.prompt) for r in live)
             wave_new = max(r.max_new for r in live)
-            self.cache = init_cache(self.cfg, self.batch, self.max_len)
+            self.reset()
             toks = np.zeros((self.batch,), np.int32)
             if obs.enabled:
                 obs.tracer.begin(track, "engine/prefill",
@@ -122,9 +134,10 @@ class ServeEngine:
                         toks[i] = r.prompt[t]
                     elif r.out and not r.done:
                         toks[i] = r.out[-1]
-                logits, self.cache = self._step(
-                    self.cache, jnp.asarray(toks), jnp.int32(t))
-                nxt = np.asarray(jnp.argmax(logits, axis=-1))
+                logits = self.step(toks, t)
+                # logits cover the padded vocab: never emit a pad id
+                nxt = np.asarray(jnp.argmax(logits[:, :self.cfg.vocab],
+                                            axis=-1))
                 for i, r in enumerate(self.slots):
                     if r is None or r.done:
                         continue

@@ -119,7 +119,7 @@ def sampled_threshold(state, cfg: TrackerConfig, target_bytes):
     key = jax.random.fold_in(jax.random.key(17), state["now"])
     idx = jax.random.randint(key, (n,), 0, cfg.n_units)
     samp = jnp.sort(scores[idx])[::-1]            # descending
-    total = cfg.n_units * cfg.unit_bytes
+    total = float(cfg.n_units * cfg.unit_bytes)    # may exceed int32
     k = jnp.clip((n * target_bytes / total).astype(jnp.int32),
                  0, n - 1)
     return samp[k]

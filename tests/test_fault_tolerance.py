@@ -12,6 +12,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 import numpy as np
 import pytest
 
@@ -80,7 +81,7 @@ def test_elastic_reshard(tmp_path):
     from jax.sharding import NamedSharding, PartitionSpec as P
     t = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
     save(str(tmp_path), 0, t, extra={})
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), (AxisType.Auto,))
     sh = {"w": NamedSharding(mesh, P("data", None))}
     got, _ = restore(str(tmp_path), 0, t, shardings=sh)
     np.testing.assert_array_equal(np.asarray(got["w"]), np.asarray(t["w"]))
@@ -143,7 +144,7 @@ def test_straggler_monitor_flags_slow_steps():
 def test_compressed_allreduce_bounded_error_and_convergence():
     from repro.distributed.compression import (compressed_allreduce,
                                                init_error_state)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), (AxisType.Auto,))
     g = {"w": jnp.asarray(np.random.default_rng(0)
                           .standard_normal((64, 64)), jnp.float32)}
     e = init_error_state(g)

@@ -33,7 +33,7 @@ def test_flash_attention(B, S, H, KVH, D, window, dtype):
     k = rand(1, (B, S, KVH, D), dtype)
     v = rand(2, (B, S, KVH, D), dtype)
     out = ops.flash_attention(q, k, v, causal=True, window=window,
-                              block_q=64, block_k=64, interpret=True)
+                              block_q=64, block_k=64)
     want = ref.flash_attention_ref(q, k, v, causal=True, window=window)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(want, np.float32),
@@ -52,8 +52,7 @@ def test_decode_attention(B, S, H, KVH, D, valid, dtype):
     q = rand(0, (B, H, D), dtype)
     k = rand(1, (B, S, KVH, D), dtype)
     v = rand(2, (B, S, KVH, D), dtype)
-    out = ops.decode_attention(q, k, v, jnp.int32(valid), block_s=128,
-                               interpret=True)
+    out = ops.decode_attention(q, k, v, jnp.int32(valid), block_s=128)
     want = ref.decode_attention_ref(q, k, v, valid)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(want, np.float32),
@@ -69,7 +68,7 @@ def test_ralt_update(N, alpha):
     hits = jnp.asarray(rng.integers(0, 2, N), jnp.int8)
     now, thresh = 57, 1.0
     nt, ns, hot = ops.ralt_update(ticks, scores, hits, now, thresh,
-                                  alpha=alpha, interpret=True)
+                                  alpha=alpha)
     want_t, want_s = ref.ralt_update_ref(ticks, scores, hits, now, alpha)
     np.testing.assert_array_equal(np.asarray(nt), np.asarray(want_t))
     np.testing.assert_allclose(np.asarray(ns), np.asarray(want_s),
@@ -90,7 +89,7 @@ def test_ssd_scan(B, nC, Q, nh, hp, ns, dtype):
     Cm = rand(2, (B, nC, Q, ns), dtype) * 0.5
     dt = jax.nn.softplus(rand(3, (B, nC, Q, nh), jnp.float32))
     A = -jnp.exp(jax.random.normal(jax.random.key(4), (nh,)) * 0.2)
-    y, hfin = ops.ssd_scan(x, Bm, Cm, dt, A, interpret=True)
+    y, hfin = ops.ssd_scan(x, Bm, Cm, dt, A)
     h0 = jnp.zeros((B, nh, ns, hp), jnp.float32)
     want_y, want_h = ref.ssd_chunk_ref(x.astype(jnp.float32),
                                        Bm.astype(jnp.float32),
@@ -110,8 +109,7 @@ def test_flash_matches_model_reference():
     q = rand(0, (2, 256, 8, 64), jnp.float32)
     k = rand(1, (2, 256, 2, 64), jnp.float32)
     v = rand(2, (2, 256, 2, 64), jnp.float32)
-    a = ops.flash_attention(q, k, v, causal=True, block_q=64, block_k=64,
-                            interpret=True)
+    a = ops.flash_attention(q, k, v, causal=True, block_q=64, block_k=64)
     b = model_flash(q, k, v, causal=True, q_chunk=128, kv_chunk=128)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=2e-5, atol=2e-5)

@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.distributed.pipeline import bubble_fraction, gpipe_apply
 
@@ -30,7 +31,7 @@ def sequential(params, xs):
 
 
 def test_single_stage_degenerate():
-    mesh = jax.make_mesh((1,), ("stage",))
+    mesh = jax.make_mesh((1,), ("stage",), (AxisType.Auto,))
     params = make(1, 8)
     xs = jax.random.normal(jax.random.key(1), (4, 2, 8))
     got = gpipe_apply(stage_fn, params, xs, mesh=mesh, axis="stage")
@@ -48,6 +49,7 @@ _SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import AxisType
 from repro.distributed.pipeline import gpipe_apply
 
 def stage_fn(p, x):
@@ -58,7 +60,7 @@ ks = jax.random.split(jax.random.key(0), 2)
 params = {"w": jax.random.normal(ks[0], (S, d, d)) * 0.3,
           "b": jax.random.normal(ks[1], (S, d)) * 0.1}
 xs = jax.random.normal(jax.random.key(1), (M, 2, d))
-mesh = jax.make_mesh((4,), ("stage",))
+mesh = jax.make_mesh((4,), ("stage",), (AxisType.Auto,))
 got = gpipe_apply(stage_fn, params, xs, mesh=mesh, axis="stage")
 
 def one(x):
@@ -75,6 +77,7 @@ print("pipeline-4stage ok")
 @pytest.mark.slow
 def test_four_stage_subprocess():
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"       # virtual CPU devices, never a chip
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..",
                                      "src")
     out = subprocess.run([sys.executable, "-c", _SCRIPT],

@@ -34,15 +34,37 @@ def test_engine_greedy_is_deterministic():
     assert outs[0] == outs[1]
 
 
+def test_engine_step_takes_params_as_arguments():
+    """The decode step's program takes every weight as an argument (a
+    closure would embed them as constants) and donates the cache."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.serving import engine
+
+    cfg = smoke_config("stablelm-3b")
+    eng = ServeEngine(cfg, batch=2, max_len=16)
+    text = engine._decode.lower(eng.params, cfg, eng.cache,
+                                jnp.zeros(2, jnp.int32),
+                                jnp.int32(0)).as_text()
+    main = text.split("func.func public @main(", 1)[1].split("->", 1)[0]
+    n_params = len(jax.tree.leaves(eng.params))
+    n_cache = len(jax.tree.leaves(eng.cache))
+    assert main.count("%arg") == n_params + n_cache + 2
+    assert main.count("tf.aliasing_output") + main.count(
+        "jax.buffer_donor") == n_cache
+
+
 _MULTIDEV_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 from repro.configs import smoke_config
 from repro.launch.train import train
 from repro.launch.steps import TrainOptions
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"), (AxisType.Auto,) * 2)
 for recipe in ("tp", "fsdp"):
     cfg = smoke_config("llama3-8b")
     _, _, h = train(cfg, steps=3, global_batch=8, seq_len=64, mesh=mesh,
@@ -63,6 +85,7 @@ def test_multidevice_execution_subprocess():
     """Real SPMD execution (not just lowering) on 8 host devices, both
     recipes + the MoE dispatch path."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"       # virtual CPU devices, never a chip
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..",
                                      "src")
     out = subprocess.run([sys.executable, "-c", _MULTIDEV_SCRIPT],

@@ -6,6 +6,7 @@ a real size-1 mesh every constraint correctly collapses to None.
 import jax
 import pytest
 from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType
 
 from repro.distributed import sharding as sh
 from repro.launch.mesh import axis_binding
@@ -28,7 +29,7 @@ def test_dedupe_tp_then_sp():
 
 
 def test_size1_mesh_drops_constraints():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), (AxisType.Auto,) * 2)
     sh.set_mesh_axes(dp=("data",), tp=("model",), mesh=mesh)
     spec = sh.logical_spec(sh.DP, sh.TP, shape=(4, 4))
     assert spec == P(None, None)
@@ -45,13 +46,13 @@ def test_sp_active_logic():
     assert not sh.sp_active()          # sp == tp: deduped
     sh.set_mesh_axes(dp=("data",), tp=(), sp=("model",))
     assert sh.sp_active()              # no mesh: trusted
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), (AxisType.Auto,) * 2)
     sh.set_mesh_axes(dp=("data",), tp=(), sp=("model",), mesh=mesh)
     assert not sh.sp_active()          # |model| == 1
 
 
 def test_axis_binding_recipes():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), (AxisType.Auto,) * 2)
     b = axis_binding(mesh, shape_kind="train", recipe="tp")
     assert b["tp"] == ("model",) and b["dp"] == ("data",)
     assert b["sp"] == ("model",)
@@ -75,7 +76,7 @@ def test_axis_binding_recipes():
 
 
 def test_moe_g_includes_context_parallel_axes():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), (AxisType.Auto,) * 2)
     b = axis_binding(mesh, shape_kind="train", recipe="fsdp",
                      batch=None, allow_sp=True)
     assert b["sp"] == ("model",)
@@ -87,7 +88,7 @@ def test_moe_g_includes_context_parallel_axes():
 def test_param_specs_moe_ff_sharded():
     from repro.configs import smoke_config
     from repro.models.transformer import init_params, param_specs
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), (AxisType.Auto,) * 2)
     cfg = smoke_config("mixtral-8x22b")
     params = jax.eval_shape(lambda k: init_params(k, cfg),
                             jax.random.key(0))

@@ -88,6 +88,21 @@ def test_sampled_threshold_targets_fraction():
     assert 0.15 < kept < 0.35, (thr, kept)
 
 
+def test_sampled_threshold_tables_over_2gib():
+    """Real KV pages (5 MiB at stablelm-3b's widths): the tracked bytes
+    exceed int32 and must not overflow the threshold arithmetic."""
+    cfg = small_cfg(n=1024, n_samples=256, unit_bytes=5 * 2**20,
+                    fast_bytes=256 * 5 * 2**20)
+    state = {**init_state(cfg), "score": jnp.arange(1024, dtype=jnp.float32)}
+    target = 0.25 * 1024 * cfg.unit_bytes
+    thr = float(sampled_threshold(state, cfg, jnp.asarray(target)))
+    assert 0.15 < (np.arange(1024) >= thr).mean() < 0.35, thr
+    tr = HotTracker(cfg)
+    tr.record_ids(jnp.arange(8))
+    tr.refresh_limits()
+    assert np.isfinite(float(tr.state["threshold"]))
+
+
 # ----------------------------------------------------------------------
 # tiered KV cache: pathways + concurrency hazard
 # ----------------------------------------------------------------------
