@@ -156,9 +156,10 @@ def make_obs(bench: str, force: bool = False):
 
 def make_serving_obs(bench: str, force: bool = False):
     """Serving-half twin of `make_obs`: builds a
-    `repro.obs.serving.ServingObservability` (trace + pool series +
-    token attribution) under the same `--trace`/`--metrics-out` flag
-    contract.  `finish_obs` works for both planes."""
+    `repro.obs.serving.ServingObservability` (trace, with its `pool` and
+    `bytes` counter lanes, + token attribution) under the same
+    `--trace`/`--metrics-out` flag contract; `--metrics-out` writes the
+    counter samples.  `finish_obs` works for both planes."""
     from repro.obs.serving import ServingObservability
     tp = flag_value("--trace", f"trace_{bench}.json")
     mp = flag_value("--metrics-out", f"metrics_{bench}.json")

@@ -14,7 +14,8 @@ promotion traffic.
 
 ``--trace[=path]`` / ``--metrics-out[=path]`` attach the serving-side
 observability plane (repro.obs.serving) and export a Perfetto trace /
-pool-series dump; a "why slow" token-attribution table is printed
+its counter samples (`pool` and `bytes` lanes, taken at the end of each
+sweep and flush); a "why slow" token-attribution table is printed
 either way when the plane is live.  ``--smoke`` (CI bench-smoke job)
 runs the quick shapes, gates on a schema-clean trace containing all
 three page-level pathway instants plus the promotion-abort instant,

@@ -145,7 +145,7 @@ def drive_tiered_kv(kvcfg, steps=KV_STEPS):
     """Fill every page with its id (mod 256, exact in bf16), run the
     traffic, and return (cache, pages promoted by compaction, by flush)."""
     kv = TieredKVCache(kvcfg)
-    obs = ServingObservability(metrics=False, attribution=False)
+    obs = ServingObservability(attribution=False)
     obs.attach(kv, "kv")
     shape = (kvcfg.n_layers, kvcfg.page_tokens, kvcfg.kv_heads,
              kvcfg.head_dim)

@@ -43,7 +43,7 @@ from .trace import Tracer
 __all__ = ["Observability", "NULL_OBS", "Tracer", "MetricsRegistry",
            "LatencyHistogram", "TierLatencyHistogram", "Series",
            "AttributionSampler", "jsonify", "ServingObservability",
-           "NULL_SERVING_OBS"]
+           "DEFAULT_SERVING_OBS"]
 
 
 def jsonify(obj):
@@ -164,4 +164,4 @@ NULL_OBS = Observability(enabled=False)
 
 # The serving-half plane (JAX tiering components + ServeEngine) lives
 # in .serving; imported last so it can reuse this module's helpers.
-from .serving import NULL_SERVING_OBS, ServingObservability  # noqa: E402
+from .serving import DEFAULT_SERVING_OBS, ServingObservability  # noqa: E402

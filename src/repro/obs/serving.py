@@ -1,68 +1,93 @@
-"""Observability for the JAX serving half (PR 9): the same
-compiled-out-by-default plane as the numpy core, attached to the
-tiering components (`TieredKVCache`, `ExpertCache`, `TieredEmbedding`)
-and the `ServeEngine`.
+"""Observability for the JAX serving half: the same compiled-out
+plane as the numpy core, attached to the tiering components
+(`TieredKVCache`, `ExpertCache`, `TieredEmbedding`), their RALT
+trackers (`HotTracker`) and the `ServeEngine`.
 
-The serving half has no `StorageSim`; simulated time is the sum of the
-attached components' `SimClock` walls (`hbm_s + pcie_s`) — each clock
-is monotone, so the sum is a valid trace clock.  The plane reads those
-clocks and the pool/page-table aggregates but never charges HBM/PCIe
-time or mutates a page table — the stats-discipline lint
-(`tools/check/stats_discipline.py`) enforces the same read-only rule
-over this module as over the core plane, with serving-specific
-forbidden calls (`sweep`, `flush_promote`, `rebalance`, `read_pages`,
-`write_page`, …) and counter/page-table stores.
+Clock
+-----
+Spans are stamped with `time.time_ns`, the host clock `jax.profiler`
+stamps its own events with, so the plane's log and a profiler trace of
+the same stretch share one time line.  While a profiler session
+records, every span is also entered as a `jax.profiler.TraceAnnotation`
+of the same name: it then sits in the `.xplane.pb` beside the device
+operations.  The in-process log is what the benchmark's readers read.
 
-Three legs, mirroring `repro.obs.Observability`:
+Gate
+----
+Every top-level call of a component (an engine step or wave, a
+`read_pages`, a `write_page`, a lookup, a routing step) asks its plane
+once, ``obs.poll()``, whether to record; the sites nested inside it
+reuse that answer through one attribute check (``if obs.enabled:``).
+The class-level default, `DEFAULT_SERVING_OBS`, records exactly while a
+profiler session records (`TraceAnnotation.is_enabled()`), clears its
+log when it sees a new session begin, and records `host/gc` spans from
+a `gc.callbacks` hook meanwhile; after `stop_trace` its log is that
+session's.  With no session a site costs one attribute load and a
+branch, and a top-level call one `is_enabled()` call.  An explicitly
+attached ``ServingObservability()`` records always, with or without a
+profiler (tests, `chip_smoke.py`, `benchmarks/tiered_serving.py`).
 
-  * `Tracer` (shared class) — spans for eviction sweeps, bulk staging
-    flushes, expert rebalances, prefill/decode waves; instants for the
-    three page-level pathways (`page/retained`,
-    `page/promo_compaction`, `page/promo_flush`), promotion aborts on
-    version mismatch (`page/promo_abort`), slot assignment and engine
-    starvation.
-  * `ServingMetricsRegistry` — cadenced series per attached component:
-    HBM-pool occupancy, staging-list depth, page hit rate by tier, and
-    cumulative PCIe promotion/demotion bytes, mirrored onto trace
-    counter lanes.
+The plane reads the components' counters and pool aggregates but never
+charges HBM/PCIe time or mutates a page table: the stats-discipline
+lint (`tools/check/stats_discipline.py`) enforces the same read-only
+rule over this module as over the core plane.
+
+Two legs:
+
+  * `Tracer` (shared class): spans for engine steps and their parts,
+    page reads and writes and their parts, RALT tracker calls, eviction
+    sweeps, bulk staging flushes, expert rebalances; instants for the
+    page-level pathways (`page/retained`, `page/promo_compaction`,
+    `page/promo_flush`, `page/promo_abort`) and the engine's request
+    timestamps; counter lanes (`pool`, `bytes`) at the end of each
+    sweep and flush.  The taxonomy is in `docs/OBSERVABILITY.md`.
   * `TokenAttributionSampler` — reservoir-sampled per-token records
-    (component kind, pages gathered, pages fetched from host, sim-time
+    (component kind, pages gathered, pages fetched from host, simulated
     cost, behind-sweep flag) feeding the "why slow" table printed by
     `benchmarks/tiered_serving.py`.
-
-Every instrumentation site in the tiering/serving modules is guarded
-by one attribute check (``if self._obs.enabled:``) against the
-class-level ``_obs = NULL_SERVING_OBS`` — an unattached component pays
-one attribute load + branch per site and allocates nothing
-(`tests/test_serving_obs.py` holds this to zero events and <3%
-overhead on the serving bench).
 """
 from __future__ import annotations
 
+import gc
+import json
+import time
+
 import numpy as np
 
-from .metrics import Series
 from .trace import Tracer
 
-__all__ = ["ServingObservability", "ServingMetricsRegistry",
-           "TokenAttributionSampler", "NULL_SERVING_OBS", "KIND_NAMES",
-           "component_sample"]
+__all__ = ["ServingObservability", "TokenAttributionSampler",
+           "DEFAULT_SERVING_OBS", "KIND_NAMES", "component_sample"]
 
 KIND_NAMES = ("kv", "emb", "expert", "engine")
 KIND_CODES = {name: i for i, name in enumerate(KIND_NAMES)}
+POOL_LANE = ("hbm_occupancy", "staging_depth", "page_hit_rate")
+BYTES_LANE = ("h2d_bytes", "d2h_bytes")
 
 
-def _unit_bytes(comp) -> int:
-    """Bytes moved per promoted/demoted unit, duck-typed per component:
-    KV pages, embedding rows, or expert blobs."""
-    cfg = getattr(comp, "cfg", None)
-    if cfg is not None and hasattr(cfg, "page_bytes"):
-        return cfg.page_bytes
-    for attr in ("row_bytes", "blob_bytes"):
-        b = getattr(comp, attr, None)
-        if b is not None:
-            return int(b)
-    return 0
+def _is_profiling() -> bool:
+    """`jax.profiler.TraceAnnotation.is_enabled()`: whether a profiler
+    session records.  JAX is imported on the first call, not with this
+    module (the numpy half imports `repro.obs`); the call then rebinds
+    this name to the profiler's own function."""
+    global _is_profiling, _Annotation, _profile_state
+    from jax._src import profiler
+    from jax.profiler import TraceAnnotation
+    _Annotation = TraceAnnotation
+    _profile_state = getattr(profiler, "_profile_state", None)
+    _is_profiling = TraceAnnotation.is_enabled
+    return _is_profiling()
+
+
+_Annotation = None      # jax.profiler.TraceAnnotation, once looked up
+_profile_state = None   # where jax.profiler keeps its session, likewise
+
+
+def _profiler_session():
+    """The object of the `jax.profiler` session in progress (None where
+    JAX keeps none, as for a capture from a profiler server): a new
+    object means a new session."""
+    return getattr(_profile_state, "profile_session", None)
 
 
 def _fast_capacity(comp) -> int:
@@ -84,13 +109,12 @@ def component_sample(comp) -> dict[str, float]:
     clock = getattr(comp, "clock", None)
     if clock is None:
         return out
-    unit = _unit_bytes(comp)
     hits = clock.fast_hits + clock.slow_hits
     out["page_hit_rate"] = clock.fast_hits / hits if hits else 0.0
-    out["promoted_bytes"] = clock.promoted * unit
-    out["demoted_bytes"] = clock.demoted * unit
-    out["pcie_s"] = clock.pcie_s
-    out["hbm_s"] = clock.hbm_s
+    for k in BYTES_LANE:
+        v = getattr(comp, k, None)
+        if v is not None:
+            out[k] = float(v)
     cap = _fast_capacity(comp)
     free = getattr(comp, "free_slots", None)
     if free is None:
@@ -103,60 +127,11 @@ def component_sample(comp) -> dict[str, float]:
     return out
 
 
-class ServingMetricsRegistry:
-    """Cadenced read-only sampler over the attached serving components.
+class _ProfilerClockTracer(Tracer):
+    """Stamps events with `time.time_ns`, the host clock `jax.profiler`
+    stamps its own with."""
 
-    Series are created lazily as ``<track>/<metric>`` so one registry
-    covers any mix of components; each is the same fixed-capacity ring
-    buffer the core plane uses."""
-
-    METRICS = ("hbm_occupancy", "staging_depth", "page_hit_rate",
-               "promoted_bytes", "demoted_bytes", "pcie_s", "hbm_s")
-
-    def __init__(self, interval_s: float = 1e-4, capacity: int = 4096,
-                 enabled: bool = True):
-        self.enabled = enabled
-        self.interval_s = interval_s
-        self.capacity = capacity
-        self.series: dict[str, Series] = {}
-        self._next_t = 0.0
-        self.n_samples = 0
-
-    def _series(self, name: str) -> Series:
-        s = self.series.get(name)
-        if s is None:
-            s = self.series[name] = Series(name, self.capacity)
-        return s
-
-    def maybe_sample(self, now: float, components, tracer=None) -> None:
-        if not self.enabled or now < self._next_t:
-            return
-        self._next_t = now + self.interval_s
-        self._sample(now, components, tracer)
-
-    def _sample(self, now: float, components, tracer) -> None:
-        self.n_samples += 1
-        for comp, track in components:
-            sample = component_sample(comp)
-            for metric, value in sample.items():
-                self._series(f"{track}/{metric}").append(now, value)
-            if tracer is not None and tracer.enabled and sample:
-                tracer.counter(track, "pool", {
-                    k: round(float(sample[k]), 6)
-                    for k in ("hbm_occupancy", "staging_depth",
-                              "page_hit_rate") if k in sample})
-                tracer.counter(track, "pcie_bytes", {
-                    k: float(sample[k]) for k in
-                    ("promoted_bytes", "demoted_bytes") if k in sample})
-
-    def to_json(self) -> dict:
-        out = {"interval_s": self.interval_s, "n_samples": self.n_samples,
-               "series": {}}
-        for name, s in self.series.items():
-            t, v = s.values()
-            out["series"][name] = {"t": [round(float(x), 9) for x in t],
-                                   "v": [float(x) for x in v]}
-        return out
+    us_per_tick = 1e-3
 
 
 class TokenAttributionSampler:
@@ -255,64 +230,110 @@ class TokenAttributionSampler:
 
 
 class ServingObservability:
-    """Tracer + serving metrics + token attribution behind one flag.
+    """Tracer + token attribution behind one flag.
 
-    ``attach(component, name=...)`` wires any tiering component or the
-    `ServeEngine`; the trace clock becomes the sum of the attached
-    components' `SimClock` walls (each monotone, so the sum is too —
-    the tracer additionally clamps against benchmark clock resets)."""
+    ``attach(component, name=...)`` wires a tiering component (and its
+    RALT tracker, on the same track) or the `ServeEngine`."""
 
     def __init__(self, enabled: bool = True, trace: bool = True,
-                 metrics: bool = True, attribution: bool = True,
-                 metrics_interval_s: float = 1e-4,
-                 attr_capacity: int = 65536,
+                 attribution: bool = True, attr_capacity: int = 65536,
                  max_events: int = 400_000):
         self.enabled = enabled
-        self.tracer = Tracer(max_events=max_events,
-                             enabled=enabled and trace)
-        self.tracer.clock = self.now
-        self.metrics = ServingMetricsRegistry(
-            interval_s=metrics_interval_s, enabled=enabled and metrics)
+        self.tracer = _ProfilerClockTracer(clock=time.time_ns,
+                                           max_events=max_events,
+                                           enabled=enabled and trace)
         self.attr = TokenAttributionSampler(capacity=attr_capacity)
         self.attribution = enabled and attribution
-        self._components: list[tuple[object, str]] = []
 
-    # -- clock ---------------------------------------------------------
-    def now(self) -> float:
-        """Serving sim-time: total simulated device seconds across the
-        attached components' clocks."""
-        t = 0.0
-        for comp, _ in self._components:
-            clock = getattr(comp, "clock", None)
-            if clock is not None:
-                t += clock.total_s
-        return t
+    # -- gate (once per top-level call) --------------------------------
+    def poll(self) -> bool:
+        """Whether this top-level call records.  An attached plane
+        records always, and annotates the profiler's trace while a
+        session records."""
+        if self.enabled:
+            self.tracer.annotation = _Annotation if _is_profiling() else None
+        return self.enabled
 
     # -- attachment ----------------------------------------------------
     def attach(self, comp, name: str = "serving") -> "ServingObservability":
         comp._obs = self
         comp._obs_track = name
-        self._components.append((comp, name))
+        tracker = getattr(comp, "tracker", None)
+        if tracker is not None:
+            tracker._obs = self
+            tracker._obs_track = name
         return self
 
-    # -- component hook (once per data-plane access) -------------------
-    def on_access(self) -> None:
-        m = self.metrics
-        if m.enabled:
-            m.maybe_sample(self.now(), self._components, self.tracer)
+    # -- counter lanes (end of each sweep / flush) ---------------------
+    def sample(self, comp, track: str) -> None:
+        """The component's `pool` lane (occupancy, staging depth, hit
+        rate) and, where it counts them, its `bytes` lane."""
+        s = component_sample(comp)
+        self.tracer.counter(track, "pool", {k: round(s[k], 6)
+                                            for k in POOL_LANE if k in s})
+        moved = {k: s[k] for k in BYTES_LANE if k in s}
+        if moved:
+            self.tracer.counter(track, "bytes", moved)
 
     # -- export --------------------------------------------------------
     def export(self, trace_path: str | None = None,
                metrics_path: str | None = None) -> None:
+        """The trace as Chrome/Perfetto JSON; `metrics_path` gets the
+        counter samples alone."""
         if trace_path:
             self.tracer.export(trace_path)
         if metrics_path:
-            import json
-
-            from . import jsonify
             with open(metrics_path, "w") as f:
-                json.dump(jsonify(self.metrics.to_json()), f)
+                json.dump([ev for ev in self.tracer.events
+                           if ev["ph"] == "C"], f)
 
 
-# The compiled-out default: every tiering/serving class's `_obs`.
-NULL_SERVING_OBS = ServingObservability(enabled=False)
+class _ProfiledServingObservability(ServingObservability):
+    """The class-level default plane: records exactly while a
+    `jax.profiler` session records, and starts an empty log with each
+    new session."""
+
+    def __init__(self):
+        super().__init__(enabled=False, attribution=False)
+        self.tracer.enabled = True      # the sites gate on self.enabled
+        self._session = None
+        self._gc_open = False
+
+    def poll(self) -> bool:
+        on = _is_profiling()
+        if on:
+            if not self.enabled or _profiler_session() is not self._session:
+                self._start()
+        elif self.enabled:
+            self._stop()
+        return on
+
+    def _start(self) -> None:
+        self.tracer.clear()
+        self._session = _profiler_session()
+        self.enabled = True
+        self.tracer.annotation = _Annotation
+        if self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
+
+    def _stop(self) -> None:
+        self.enabled = False
+        self.tracer.annotation = None
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        """`host/gc`: a collection while the session records (the hook
+        outlives `stop_trace` until the next poll)."""
+        if phase == "start" and self.enabled and _is_profiling():
+            self._gc_open = True
+            self.tracer.begin("host", "host/gc",
+                              {"generation": info["generation"]})
+        elif phase == "stop" and self._gc_open:
+            self._gc_open = False
+            self.tracer.end("host", "host/gc",
+                            {"collected": info["collected"]})
+
+
+# The default of every tiering/serving class's `_obs`.
+DEFAULT_SERVING_OBS = _ProfiledServingObservability()

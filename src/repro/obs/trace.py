@@ -13,10 +13,17 @@ Timestamps come from a ``clock`` callable returning *simulated*
 seconds (`Observability.now` wires it to the cluster's bottleneck
 device wall, ``StorageSim.sim_time``): spans measure how much
 simulated device time elapsed inside them, which is the quantity the
-paper's claims are about.  Wall-clock tracers (kernel benches) pass
-``time.perf_counter``-style clocks instead.  Emitted timestamps are
-clamped monotone so a ``reset_storage()`` mid-attachment can never
-produce a trace Perfetto refuses to order.
+paper's claims are about.  Wall-clock tracers pass a host clock
+instead (the serving plane passes the profiler's, `time.time_ns`).
+Emitted timestamps are clamped monotone so a ``reset_storage()``
+mid-attachment can never produce a trace Perfetto refuses to order.
+
+Each ``B`` event carries a span ``id`` and the ``parent`` id of the span
+open on its track when it began (None at the top of the track); its
+``E`` event carries the same ``id``.  While ``annotation`` is set (the
+serving plane sets it to ``jax.profiler.TraceAnnotation`` while a
+profiler session records), each span is also entered as
+``annotation(name)``, so it appears in the profiler's own trace too.
 
 The recorder is bounded: past ``max_events`` new events are counted in
 ``dropped`` instead of stored, so tracing can stay on for a whole
@@ -38,6 +45,9 @@ __all__ = ["Tracer"]
 class Tracer:
     """Append-only, bounded, monotonically-timestamped event recorder."""
 
+    us_per_tick = 1e6           # microseconds per unit of `clock()`
+    annotation = None           # span context factory, e.g. TraceAnnotation
+
     def __init__(self, clock=None, max_events: int = 400_000,
                  enabled: bool = True):
         self.clock = clock                # callable -> seconds (sim or wall)
@@ -46,46 +56,65 @@ class Tracer:
         self.events: list[dict] = []
         self.dropped = 0
         self._last_us = 0.0
-        self._depth: dict[str, list[str]] = {}   # track -> open-span stack
+        self._next_id = 0
+        # track -> open spans: (B event, entered annotation or None)
+        self._depth: dict[str, list[tuple]] = {}
 
     # -- core ----------------------------------------------------------
-    def _ts(self) -> float:
-        t = self.clock() if self.clock is not None else 0.0
-        us = float(t) * 1e6
-        if us < self._last_us:            # reset_storage / clock rebinds
-            us = self._last_us
-        self._last_us = us
-        return us
-
     def _push(self, ev: dict) -> None:
+        """Stamp and store one event.  The stamp is taken after the last
+        call of this method, so a garbage collection (whose callback may
+        record events of its own) runs wholly before it and the list
+        stays in timestamp order."""
         if len(self.events) >= self.max_events:
             self.dropped += 1
             return
+        us = float(self.clock() if self.clock is not None else 0.0) \
+            * self.us_per_tick
+        if us < self._last_us:            # reset_storage / clock rebinds
+            us = self._last_us
+        self._last_us = us
+        ev["ts"] = us
         self.events.append(ev)
 
     # -- emitters ------------------------------------------------------
     def begin(self, track: str, name: str, args: dict | None = None) -> None:
         if not self.enabled:
             return
-        self._depth.setdefault(track, []).append(name)
-        ev = {"track": track, "name": name, "ph": "B", "ts": self._ts()}
+        stack = self._depth.setdefault(track, [])
+        self._next_id += 1
+        ev = {"track": track, "name": name, "ph": "B", "id": self._next_id,
+              "parent": stack[-1][0]["id"] if stack else None}
         if args:
             ev["args"] = args
         self._push(ev)
+        ann = None
+        if self.annotation is not None:
+            ann = self.annotation(name)
+            ann.__enter__()
+        stack.append((ev, ann))
 
     def end(self, track: str, name: str | None = None,
             args: dict | None = None) -> None:
         if not self.enabled:
             return
+        ev = {"track": track, "ph": "E"}
         stack = self._depth.get(track)
         if stack:
-            opened = stack.pop()
-            name = name or opened
-        ev = {"track": track, "name": name or "?", "ph": "E",
-              "ts": self._ts()}
+            opened, ann = stack.pop()
+            if ann is not None:
+                ann.__exit__(None, None, None)
+            name = name or opened["name"]
+            ev["id"] = opened["id"]
+        ev["name"] = name or "?"
         if args:
             ev["args"] = args
         self._push(ev)
+
+    def swap(self, track: str, name: str, args: dict | None = None) -> None:
+        """End the innermost span on `track` and begin its next sibling."""
+        self.end(track)
+        self.begin(track, name, args)
 
     def span(self, track: str, name: str, args: dict | None = None):
         """``with tracer.span(...):`` — B on entry, E on exit (also on
@@ -96,8 +125,7 @@ class Tracer:
                 args: dict | None = None) -> None:
         if not self.enabled:
             return
-        ev = {"track": track, "name": name, "ph": "i", "ts": self._ts(),
-              "s": "t"}
+        ev = {"track": track, "name": name, "ph": "i", "s": "t"}
         if args:
             ev["args"] = args
         self._push(ev)
@@ -107,7 +135,7 @@ class Tracer:
         if not self.enabled:
             return
         self._push({"track": track, "name": name, "ph": "C",
-                    "ts": self._ts(), "args": values})
+                    "args": values})
 
     def close_open(self, args: dict | None = None) -> int:
         """Close every open span on every track (crash salvage: when an
@@ -120,6 +148,15 @@ class Tracer:
                 self.end(track, args=args)
                 closed += 1
         return closed
+
+    def clear(self) -> None:
+        """Forget the events recorded so far (a new recording begins).
+        Spans still open keep their B events, so the log stays
+        stack-balanced when they end."""
+        self.events = sorted((ev for stack in self._depth.values()
+                              for ev, _ in stack if "ts" in ev),
+                             key=lambda ev: ev["ts"])
+        self.dropped = 0
 
     # -- integrity -----------------------------------------------------
     def validate(self) -> list[str]:
@@ -194,6 +231,9 @@ class Tracer:
                 e["s"] = ev["s"]
             if "args" in ev:
                 e["args"] = ev["args"]
+            if ev["ph"] == "B":
+                e["args"] = {**ev.get("args", {}), "span_id": ev["id"],
+                             "parent_id": ev["parent"]}
             out.append(e)
         return {"traceEvents": out, "displayTimeUnit": "ms",
                 "otherData": {"dropped_events": self.dropped}}

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.transformer import decode_step, init_cache, init_params
-from ..obs.serving import NULL_SERVING_OBS
+from ..obs.serving import DEFAULT_SERVING_OBS
 
 # The parameters are an argument, never closed over: JAX embeds a
 # closed-over array in the program as a constant (gigabytes at published
@@ -38,8 +38,8 @@ class Request:
 
 
 class ServeEngine:
-    # Compiled-out-by-default obs plane (see repro.obs.serving).
-    _obs = NULL_SERVING_OBS
+    # Obs plane: records while a profiler session does (repro.obs.serving).
+    _obs = DEFAULT_SERVING_OBS
     _obs_track = "engine"
 
     def __init__(self, cfg, params=None, *, batch: int = 8,
@@ -55,6 +55,7 @@ class ServeEngine:
         self.queue: list = []
         self.completed: list = []
         self.steps_used = 0
+        self.waves = 0                  # waves run over the engine's life
         self.starved = False            # budget expired with live work
 
     def submit(self, req: Request):
@@ -65,12 +66,19 @@ class ServeEngine:
         return len(self.completed)
 
     def _assign(self) -> int:
-        assigned = 0
+        """Fill free slots from the queue.  The wave's gate: the plane is
+        asked here, after the pops, whether this wave records."""
+        admitted = []
         for i in range(self.batch):
             if self.slots[i] is None and self.queue:
                 self.slots[i] = self.queue.pop(0)
-                assigned += 1
-        return assigned
+                admitted.append(self.slots[i].rid)
+        obs = self._obs
+        if obs.poll():
+            for rid in admitted:
+                obs.tracer.instant(self._obs_track, "engine/admit",
+                                   {"rid": rid})
+        return len(admitted)
 
     def __getstate__(self):
         state = dict(self.__dict__)
@@ -101,6 +109,7 @@ class ServeEngine:
         slots or queued requests the engine sets `starved`, emits a
         traced `engine/starved` instant, and returns what completed."""
         obs, track = self._obs, self._obs_track
+        tr = obs.tracer
         self.steps_used = 0
         self.starved = False
         while (self.queue or any(self.slots)) and max_steps:
@@ -108,25 +117,30 @@ class ServeEngine:
             live = [r for r in self.slots if r is not None]
             if not live:
                 break
-            if obs.enabled and assigned:
-                obs.tracer.instant(track, "engine/assign",
-                                   {"assigned": assigned,
-                                    "queued": len(self.queue)})
+            wave = obs.enabled          # the answer _assign's poll gave
+            if wave:
+                if assigned:
+                    tr.instant(track, "engine/assign",
+                               {"assigned": assigned,
+                                "queued": len(self.queue)})
+                tr.begin(track, "engine/reset")
             wave_prompt = max(len(r.prompt) for r in live)
             wave_new = max(r.max_new for r in live)
             self.reset()
             toks = np.zeros((self.batch,), np.int32)
-            if obs.enabled:
-                obs.tracer.begin(track, "engine/prefill",
-                                 {"live": len(live),
-                                  "prompt_len": wave_prompt})
+            if wave:
+                tr.swap(track, "engine/prefill",
+                        {"live": len(live), "prompt_len": wave_prompt})
             # teacher-forced prefill (exact; shares the decode step)
             for t in range(wave_prompt + wave_new):
-                if obs.enabled and t == wave_prompt:
-                    obs.tracer.end(track, "engine/prefill")
-                    obs.tracer.begin(track, "engine/decode",
-                                     {"live": len(live),
-                                      "max_new": wave_new})
+                if wave and t == wave_prompt:
+                    tr.swap(track, "engine/decode",
+                            {"live": len(live), "max_new": wave_new})
+                rec = obs.poll()
+                if rec:
+                    tr.begin(track, "engine/step",
+                             {"wave": self.waves, "step": t})
+                    tr.begin(track, "engine/feed")
                 for i, r in enumerate(self.slots):
                     if r is None:
                         continue
@@ -134,23 +148,36 @@ class ServeEngine:
                         toks[i] = r.prompt[t]
                     elif r.out and not r.done:
                         toks[i] = r.out[-1]
+                if rec:
+                    tr.swap(track, "engine/dispatch")
                 logits = self.step(toks, t)
                 # logits cover the padded vocab: never emit a pad id
-                nxt = np.asarray(jnp.argmax(logits[:, :self.cfg.vocab],
-                                            axis=-1))
+                nxt = jnp.argmax(logits[:, :self.cfg.vocab], axis=-1)
+                if rec:
+                    tr.swap(track, "engine/sync")
+                nxt = np.asarray(nxt)
+                if rec:
+                    tr.swap(track, "engine/emit")
                 for i, r in enumerate(self.slots):
                     if r is None or r.done:
                         continue
                     if t >= len(r.prompt) - 1:
+                        if rec and not r.out:
+                            tr.instant(track, "engine/first_token",
+                                       {"rid": r.rid})
                         r.out.append(int(nxt[i]))
                         if len(r.out) >= r.max_new:
                             r.done = True
+                if rec:
+                    tr.end(track, "engine/emit")
+                    tr.end(track, "engine/step")
                 max_steps -= 1
                 self.steps_used += 1
                 if max_steps <= 0:
                     break
-            if obs.enabled:
-                obs.tracer.end(track)   # close prefill OR decode span
+            if wave:
+                tr.end(track)   # close prefill OR decode span
+            self.waves += 1
             for i, r in enumerate(self.slots):
                 if r is not None and r.done:
                     self.completed.append(r)

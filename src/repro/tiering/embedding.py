@@ -15,14 +15,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..obs.serving import NULL_SERVING_OBS
+from ..obs.serving import DEFAULT_SERVING_OBS
 from .hotness import HotTracker, TrackerConfig
 from .kvcache import HBM_BW, PCIE_BW, SimClock
 
 
 class TieredEmbedding:
-    # Compiled-out-by-default obs plane (see repro.obs.serving).
-    _obs = NULL_SERVING_OBS
+    # Obs plane: records while a profiler session does (repro.obs.serving).
+    _obs = DEFAULT_SERVING_OBS
     _obs_track = "emb"
 
     def __init__(self, table: np.ndarray, fast_rows: int,
@@ -40,12 +40,14 @@ class TieredEmbedding:
         self.tracker = HotTracker(TrackerConfig(
             n_units=V, unit_bytes=self.row_bytes,
             fast_bytes=fast_rows * self.row_bytes))
+        self.tracker._obs_track = self._obs_track
         self.clock = SimClock()
 
     def lookup(self, token_ids) -> jnp.ndarray:
         """Exact gather (resident rows from HBM, misses from host)."""
         obs = self._obs
-        if obs.enabled:
+        rec = obs.poll()
+        if rec:
             t0 = self.clock.total_s
             f0 = self.clock.flushes
         ids = np.asarray(token_ids).reshape(-1)
@@ -68,12 +70,9 @@ class TieredEmbedding:
         self.tracker.record_ids(jnp.asarray(np.unique(ids), jnp.int32))
         if len(self.staging) >= self.staging_slots:
             self.flush_promote()
-        if obs.enabled:
-            if obs.attribution:
-                obs.attr.observe(
-                    "emb", self.clock.total_s - t0, len(ids),
-                    int(miss.sum()), self.clock.flushes > f0)
-            obs.on_access()
+        if rec and obs.attribution:
+            obs.attr.observe("emb", self.clock.total_s - t0, len(ids),
+                             int(miss.sum()), self.clock.flushes > f0)
         return jnp.asarray(out).reshape(*np.shape(token_ids), -1)
 
     def flush_promote(self):
@@ -85,7 +84,7 @@ class TieredEmbedding:
                              {"staged": len(self.staging)})
             r0, p0 = c.retained, c.promoted
         self.tracker.refresh_limits()
-        hot = np.asarray(self.tracker.hot())
+        hot = self.tracker.hot()
         scores = np.asarray(self.tracker.scores())
         want = [r for r in self.staging if hot[r]]
         self.staging.clear()
@@ -133,6 +132,7 @@ class TieredEmbedding:
                            {"pages": c.promoted - p0})
             tr.end(track, "emb/flush_promote",
                    {"promoted": c.promoted - p0})
+            obs.sample(self, track)
 
     def __getstate__(self):
         state = dict(self.__dict__)

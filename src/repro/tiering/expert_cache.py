@@ -14,14 +14,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..obs.serving import NULL_SERVING_OBS
+from ..obs.serving import DEFAULT_SERVING_OBS
 from .hotness import HotTracker, TrackerConfig
 from .kvcache import HBM_BW, PCIE_BW, SimClock
 
 
 class ExpertCache:
-    # Compiled-out-by-default obs plane (see repro.obs.serving).
-    _obs = NULL_SERVING_OBS
+    # Obs plane: records while a profiler session does (repro.obs.serving).
+    _obs = DEFAULT_SERVING_OBS
     _obs_track = "expert"
 
     def __init__(self, expert_weights: np.ndarray, fast_experts: int,
@@ -40,6 +40,7 @@ class ExpertCache:
         self.tracker = HotTracker(TrackerConfig(
             n_units=E, unit_bytes=self.blob_bytes,
             fast_bytes=fast_experts * self.blob_bytes))
+        self.tracker._obs_track = self._obs_track
         self.clock = SimClock()
         self.swap_every = swap_every
         self._steps = 0
@@ -49,7 +50,8 @@ class ExpertCache:
         Resident experts are HBM reads; non-resident experts are
         streamed from host (PCIe) for this step and staged."""
         obs, c = self._obs, self.clock
-        if obs.enabled:
+        rec = obs.poll()
+        if rec:
             t0 = c.total_s
             s0, m0 = c.slow_hits, c.sweeps
         used = np.nonzero(expert_counts > 0)[0]
@@ -65,11 +67,9 @@ class ExpertCache:
         self._steps += 1
         if self._steps % self.swap_every == 0:
             self.rebalance()
-        if obs.enabled:
-            if obs.attribution:
-                obs.attr.observe("expert", c.total_s - t0, len(used),
-                                 c.slow_hits - s0, c.sweeps > m0)
-            obs.on_access()
+        if rec and obs.attribution:
+            obs.attr.observe("expert", c.total_s - t0, len(used),
+                             c.slow_hits - s0, c.sweeps > m0)
 
     def rebalance(self):
         """Sweep: retain hot residents, demote cold ones, promote the
@@ -82,7 +82,7 @@ class ExpertCache:
             r0, d0, p0 = c.retained, c.demoted, c.promoted
         self.tracker.refresh_limits()
         scores = np.asarray(self.tracker.scores())
-        hot = np.asarray(self.tracker.hot())
+        hot = self.tracker.hot()
         order = np.argsort(-scores)
         want = [int(e) for e in order[:self.fast_experts] if hot[e]]
         want_set = set(want)
@@ -120,6 +120,7 @@ class ExpertCache:
             tr.end(track, "expert/rebalance",
                    {"demoted": c.demoted - d0,
                     "promoted": c.promoted - p0})
+            obs.sample(self, track)
 
     def __getstate__(self):
         state = dict(self.__dict__)

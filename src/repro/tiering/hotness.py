@@ -15,8 +15,12 @@ score table — but the *algorithms* are the paper's, unchanged:
     (+delta_c per hit, capped c_max, -1 per R bytes accessed) and
     stability tags t; limit = clamp(stable_size + D_hs, [L_hs, R_hs]).
 
-Everything is jit-compatible (fixed shapes); the host only reads back
-scalars (hot set size, limits) for orchestration decisions.
+Everything is jit-compatible (fixed shapes); the host reads back the
+hot mask and scalars (hot set size, limits) for orchestration decisions.
+
+`HotTracker`'s calls are spans of the serving observability plane
+(`ralt/record`, `ralt/limits`, `ralt/hot_mask`), on the track of the
+component that owns the tracker, so they nest under its spans.
 """
 from __future__ import annotations
 
@@ -24,8 +28,10 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..kernels import ops as kops
+from ..obs.serving import DEFAULT_SERVING_OBS
 
 F32 = jnp.float32
 
@@ -148,6 +154,10 @@ def hot_mask(state, cfg: TrackerConfig):
 class HotTracker:
     """Convenience stateful wrapper (jitted pure ops inside)."""
 
+    # The owner's plane and track (`ServingObservability.attach`).
+    _obs = DEFAULT_SERVING_OBS
+    _obs_track = "ralt"
+
     def __init__(self, cfg: TrackerConfig):
         self.cfg = cfg
         self.state = init_state(cfg)
@@ -163,7 +173,7 @@ class HotTracker:
     def __getstate__(self):
         """Jitted closures don't pickle; rebuild them on load."""
         state = dict(self.__dict__)
-        for k in ("_record", "_limits", "_hot"):
+        for k in ("_record", "_limits", "_hot", "_obs"):
             state.pop(k, None)
         return state
 
@@ -172,17 +182,34 @@ class HotTracker:
         self._build_jits()
 
     def record(self, hit_mask):
+        obs = self._obs
+        if obs.enabled:
+            obs.tracer.begin(self._obs_track, "ralt/record")
         self.state = self._record(self.state, hit_mask)
+        if obs.enabled:
+            obs.tracer.end(self._obs_track, "ralt/record")
 
     def record_ids(self, ids):
         mask = jnp.zeros(self.cfg.n_units, bool).at[ids].set(True)
         self.record(mask)
 
     def refresh_limits(self):
+        obs = self._obs
+        if obs.enabled:
+            obs.tracer.begin(self._obs_track, "ralt/limits")
         self.state = self._limits(self.state)
+        if obs.enabled:
+            obs.tracer.end(self._obs_track, "ralt/limits")
 
     def hot(self):
-        return self._hot(self.state)
+        """The hot mask on the host: waits for the tracker's device work."""
+        obs = self._obs
+        if obs.enabled:
+            obs.tracer.begin(self._obs_track, "ralt/hot_mask")
+        mask = np.asarray(self._hot(self.state))
+        if obs.enabled:
+            obs.tracer.end(self._obs_track, "ralt/hot_mask")
+        return mask
 
     def scores(self):
         return current_scores(self.state, self.cfg)

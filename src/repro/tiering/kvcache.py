@@ -23,7 +23,11 @@ promote" hazard).  The abort path is exercised in tests.
 The device-side data plane (gathers, copies) is jax; the control plane
 (page table, sweeps) is host Python — same split as an LSM-tree's
 I/O vs. manifest logic.  `SimClock` charges HBM/PCIe time so benchmarks
-report the paper-style simulated throughput on CPU.
+report the paper-style simulated throughput on CPU.  The bytes the cache
+really moves are plain counters on it: `h2d_bytes` and `d2h_bytes`
+(host-to-device and device-to-host copies of pages), and
+`pool_copy_bytes` (a non-donated `.at[s].set` writes a new pool: it
+copies the whole HBM pool).
 """
 from __future__ import annotations
 
@@ -33,7 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..obs.serving import NULL_SERVING_OBS
+from ..obs.serving import DEFAULT_SERVING_OBS
 from .hotness import HotTracker, TrackerConfig
 
 HBM_BW = 819e9      # v5e bytes/s
@@ -79,9 +83,9 @@ class SimClock:
 class TieredKVCache:
     TIER_FAST, TIER_SLOW = 0, 1
 
-    # Observability (repro.obs.serving) is compiled out by default:
-    # class-level null plane, one attribute check per site.
-    _obs = NULL_SERVING_OBS
+    # Observability (repro.obs.serving): the class-level default plane
+    # records while a profiler session does; one attribute check a site.
+    _obs = DEFAULT_SERVING_OBS
     _obs_track = "kv"
 
     def __init__(self, cfg: KVTierConfig, tracker_cfg: TrackerConfig
@@ -104,36 +108,75 @@ class TieredKVCache:
         self.tracker = HotTracker(tracker_cfg or TrackerConfig(
             n_units=cfg.n_pages, unit_bytes=cfg.page_bytes,
             fast_bytes=cfg.fast_slots * cfg.page_bytes))
+        self.tracker._obs_track = self._obs_track
         self.clock = SimClock()
         self._access_count = 0
+        self.h2d_bytes = 0
+        self.d2h_bytes = 0
+        self.pool_copy_bytes = 0
+
+    def _moved(self, b0) -> dict:
+        """Bytes moved since the counters read `b0` (a span's end args)."""
+        return {"h2d_bytes": self.h2d_bytes - b0[0],
+                "d2h_bytes": self.d2h_bytes - b0[1],
+                "pool_copy_bytes": self.pool_copy_bytes - b0[2]}
+
+    def _set_slot(self, s, data):
+        """Copy one page from the host into HBM slot `s`."""
+        self.fast_pool = self.fast_pool.at[s].set(
+            jnp.asarray(data, self.fast_pool.dtype))
+        self.h2d_bytes += self.cfg.page_bytes
+        self.pool_copy_bytes += self.cfg.fast_slots * self.cfg.page_bytes
 
     # ------------------------------------------------------------------
     # data plane
     # ------------------------------------------------------------------
     def write_page(self, page: int, k, v):
         """Append/overwrite a page (prefill writes; bumps version)."""
+        obs = self._obs
+        rec = obs.poll()
+        resident = self.tier[page] == self.TIER_FAST
+        if rec:
+            tr, track = obs.tracer, self._obs_track
+            b0 = (self.h2d_bytes, self.d2h_bytes, self.pool_copy_bytes)
+            tr.begin(track, "kv/write_page",
+                     {"page": int(page), "tier": int(self.tier[page])})
+            tr.begin(track, "kv/write_stage")
         self.version[page] += 1
         data = np.stack([np.asarray(k), np.asarray(v)])
-        if self.tier[page] == self.TIER_FAST:
-            s = self.slot_of[page]
-            self.fast_pool = self.fast_pool.at[s].set(
-                jnp.asarray(data, self.fast_pool.dtype))
+        if resident:
+            if rec:
+                tr.swap(track, "kv/write_resident")
+            self._set_slot(self.slot_of[page], data)
             self.clock.hbm_s += self.cfg.page_bytes / HBM_BW
         else:
+            if rec:
+                tr.swap(track, "kv/write_host")
             self.slow_pool[page] = data
             self.clock.pcie_s += self.cfg.page_bytes / PCIE_BW
+        if rec:
+            tr.end(track)
+            tr.end(track, "kv/write_page", self._moved(b0))
 
     def read_pages(self, pages):
         """Gather pages for attention.  Fast pages: one device gather;
         slow pages: host fetch (PCIe-charged) + staged for promotion."""
         obs = self._obs
-        if obs.enabled:
+        rec = obs.poll()
+        if rec:
+            tr, track = obs.tracer, self._obs_track
             t0 = self.clock.total_s
             m0 = self.clock.sweeps + self.clock.flushes
+            b0 = (self.h2d_bytes, self.d2h_bytes, self.pool_copy_bytes)
+            tr.begin(track, "kv/read_pages",
+                     {"read": self._access_count, "pages": len(pages)})
+            tr.begin(track, "kv/lookup")
         pages = list(int(p) for p in pages)
         out = {}
         fast = [p for p in pages if self.tier[p] == self.TIER_FAST]
         slow = [p for p in pages if self.tier[p] == self.TIER_SLOW]
+        if rec:
+            tr.swap(track, "kv/gather")
         if fast:
             slots = jnp.asarray([self.slot_of[p] for p in fast])
             gathered = jnp.take(self.fast_pool, slots, axis=0)
@@ -141,6 +184,8 @@ class TieredKVCache:
                 out[p] = gathered[i]
             self.clock.hbm_s += len(fast) * self.cfg.page_bytes / HBM_BW
             self.clock.fast_hits += len(fast)
+        if rec:
+            tr.swap(track, "kv/host_fetch")
         for p in slow:
             out[p] = jnp.asarray(self.slow_pool[p])
             self.clock.pcie_s += self.cfg.page_bytes / PCIE_BW
@@ -148,17 +193,23 @@ class TieredKVCache:
             # insert into the staging list (the mPC analogue) with the
             # version observed at read time (§3.3 check)
             self.staging.setdefault(p, int(self.version[p]))
+        self.h2d_bytes += len(slow) * self.cfg.page_bytes
+        if rec:
+            tr.swap(track, "kv/track")
         self._record(pages)
+        if rec:
+            tr.end(track, "kv/track")
         self._maybe_flush()
         self._access_count += 1
         if self._access_count % self.cfg.sweep_every == 0:
             self.sweep()
-        if obs.enabled:
+        if rec:
             if obs.attribution:
                 obs.attr.observe(
                     "kv", self.clock.total_s - t0, len(pages), len(slow),
                     self.clock.sweeps + self.clock.flushes > m0)
-            obs.on_access()
+            tr.end(track, "kv/read_pages",
+                   {"slow": len(slow), **self._moved(b0)})
         return [out[p] for p in pages]
 
     # ------------------------------------------------------------------
@@ -169,7 +220,7 @@ class TieredKVCache:
 
     def _hot_set(self):
         self.tracker.refresh_limits()
-        return np.asarray(self.tracker.hot())
+        return self.tracker.hot()
 
     # ------------------------------------------------------------------
     # pathways
@@ -199,8 +250,7 @@ class TieredKVCache:
         if not self.free_slots:
             return False                              # retry next sweep
         s = self.free_slots.pop()
-        self.fast_pool = self.fast_pool.at[s].set(
-            jnp.asarray(self.slow_pool[page], self.fast_pool.dtype))
+        self._set_slot(s, self.slow_pool[page])
         self.tier[page] = self.TIER_FAST
         self.slot_of[page] = s
         self.page_of_slot[s] = page
@@ -212,6 +262,7 @@ class TieredKVCache:
     def _demote(self, page: int):
         s = self.slot_of[page]
         self.slow_pool[page] = np.asarray(self.fast_pool[s])
+        self.d2h_bytes += self.cfg.page_bytes
         self.tier[page] = self.TIER_SLOW
         self.slot_of[page] = -1
         self.page_of_slot[s] = -1
@@ -251,6 +302,7 @@ class TieredKVCache:
             tr.end(track, "kv/sweep",
                    {"demoted": c.demoted - d0, "promoted": c.promoted - p0,
                     "aborted": c.aborted - a0})
+            obs.sample(self, track)
 
     def _maybe_flush(self):
         """Promotion by flush: staging full between sweeps."""
@@ -274,6 +326,7 @@ class TieredKVCache:
             obs.tracer.end(self._obs_track, "kv/staging_flush",
                            {"promoted": c.promoted - p0,
                             "aborted": c.aborted - a0})
+            obs.sample(self, self._obs_track)
 
     # ------------------------------------------------------------------
     def __getstate__(self):

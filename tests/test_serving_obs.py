@@ -1,10 +1,11 @@
-"""Serving-side observability plane (PR 9, repro.obs.serving):
-disabled mode records nothing / pickles cleanly / stays under the 3%
-overhead budget; span nesting validates across a forced eviction sweep
-and a bulk staging flush; all three page-level pathway instants and
-the seeded version-mismatch promotion abort appear; the metrics
-registry samples pool series on its sim-time cadence; the engine's
-step budget is no longer silent.
+"""Serving-side observability plane (repro.obs.serving): with no
+profiler session the default plane records nothing, components pickle
+cleanly, a disabled plane stays under the 3% overhead budget; span
+nesting validates across a forced eviction sweep and a bulk staging
+flush; all three page-level pathway instants and the seeded
+version-mismatch promotion abort appear; the counter lanes are sampled
+at the end of each sweep and flush; the engine's step budget is no
+longer silent.
 """
 import gc
 import pickle
@@ -14,7 +15,7 @@ import numpy as np
 import pytest
 
 from repro.configs import smoke_config
-from repro.obs.serving import (NULL_SERVING_OBS, ServingObservability,
+from repro.obs.serving import (DEFAULT_SERVING_OBS, ServingObservability,
                                TokenAttributionSampler, component_sample)
 from repro.serving.engine import Request, ServeEngine
 from repro.tiering import (ExpertCache, KVTierConfig, TieredEmbedding,
@@ -38,18 +39,21 @@ def drive(kv, n_ops=200, seed=0, zipf=1.5, width=4):
 # disabled mode: zero events, pickles cleanly, bounded overhead
 # ----------------------------------------------------------------------
 def test_disabled_serving_obs_records_nothing():
+    """With no profiler session the default plane records nothing (its
+    log may still hold an earlier session's events)."""
     kv = TieredKVCache(small_cfg())
-    assert kv._obs is NULL_SERVING_OBS
+    assert kv._obs is DEFAULT_SERVING_OBS
+    before = list(DEFAULT_SERVING_OBS.tracer.events)
     drive(kv)
-    assert NULL_SERVING_OBS.tracer.events == []
-    assert NULL_SERVING_OBS.metrics.n_samples == 0
-    assert NULL_SERVING_OBS.attr.n_seen == 0
+    assert DEFAULT_SERVING_OBS.enabled is False
+    assert DEFAULT_SERVING_OBS.tracer.events == before
+    assert DEFAULT_SERVING_OBS.attr.n_seen == 0
 
 
 def test_components_pickle_cleanly():
     """Unattached AND attached components round-trip through pickle:
     __getstate__ drops the plane (and HotTracker's jitted closures),
-    the class-level NULL plane reasserts on load."""
+    the class-level default plane reasserts on load."""
     kv = TieredKVCache(small_cfg())
     drive(kv, 64)
     emb = TieredEmbedding(np.zeros((32, 4), np.float32), fast_rows=8)
@@ -59,7 +63,7 @@ def test_components_pickle_cleanly():
         obs.attach(comp, name)
     for comp in (kv, emb, ec):
         clone = pickle.loads(pickle.dumps(comp))
-        assert clone._obs is NULL_SERVING_OBS
+        assert clone._obs is DEFAULT_SERVING_OBS
         assert clone.clock.total_s == comp.clock.total_s
     kv2 = pickle.loads(pickle.dumps(kv))
     drive(kv2, 16)                    # rebuilt tracker jits still work
@@ -173,24 +177,28 @@ def test_version_mismatch_abort_emits_instant():
 # ----------------------------------------------------------------------
 # metrics + attribution
 # ----------------------------------------------------------------------
-def test_pool_series_sampled_on_cadence():
+def test_counter_lanes_at_sweep_end():
+    """Each sweep and flush ends with one `pool` and one `bytes` counter
+    sample, read from the component's own aggregates."""
     kv = TieredKVCache(small_cfg())
-    obs = ServingObservability(metrics_interval_s=1e-7)
-    obs.attach(kv, "kv")
+    obs = ServingObservability().attach(kv, "kv")
     drive(kv, 150)
-    m = obs.metrics
-    assert m.n_samples > 2
-    for metric in ("hbm_occupancy", "staging_depth", "page_hit_rate",
-                   "promoted_bytes", "demoted_bytes"):
-        t, v = m.series[f"kv/{metric}"].values()
-        assert len(t) == len(v) > 0
-        assert np.all(np.diff(t) >= 0)
-    occ = m.series["kv/hbm_occupancy"].values()[1]
-    assert all(0.0 <= x <= 1.0 for x in occ)
-    # counter mirrors land on the trace
-    assert {"pool", "pcie_bytes"} <= obs.tracer.names()
-    doc = m.to_json()
-    assert doc["n_samples"] == m.n_samples
+    evs = obs.tracer.events
+    ends = [i for i, e in enumerate(evs) if e["ph"] == "E"
+            and e["name"] in ("kv/sweep", "kv/staging_flush")]
+    assert len(ends) == kv.clock.sweeps + kv.clock.flushes > 2
+    for i in ends:
+        assert [e["name"] for e in evs[i + 1:i + 3]] == ["pool", "bytes"]
+        assert all(e["ph"] == "C" for e in evs[i + 1:i + 3])
+    pool = [e["args"] for e in evs if e["ph"] == "C" and e["name"] == "pool"]
+    assert all(set(a) == {"hbm_occupancy", "staging_depth", "page_hit_rate"}
+               and 0.0 <= a["hbm_occupancy"] <= 1.0 for a in pool)
+    moved = [e["args"] for e in evs if e["ph"] == "C" and e["name"] == "bytes"]
+    assert moved[-1] == {"h2d_bytes": float(kv.h2d_bytes),
+                         "d2h_bytes": float(kv.d2h_bytes)}
+    h2d = [a["h2d_bytes"] for a in moved]
+    assert h2d == sorted(h2d) and h2d[-1] > 0
+    assert not {"pcie_bytes", "pcie_s", "hbm_s"} & obs.tracer.names()
 
 
 def test_component_sample_reads_only():
@@ -203,7 +211,8 @@ def test_component_sample_reads_only():
             list(kv.free_slots)) == before
     assert 0.0 <= s["page_hit_rate"] <= 1.0
     assert 0.0 <= s["hbm_occupancy"] <= 1.0
-    assert s["promoted_bytes"] == kv.clock.promoted * kv.cfg.page_bytes
+    assert s["h2d_bytes"] == kv.h2d_bytes == kv.cfg.page_bytes * (
+        kv.clock.slow_hits + kv.clock.promoted)
 
 
 def test_attribution_reservoir_and_table():
