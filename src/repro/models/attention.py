@@ -91,23 +91,43 @@ def attn_block(p, x, cfg, window: int | None, positions=None,
     return shard(x, DP, SP, None), (k, v)
 
 
-def attn_decode(p, x, cache_k, cache_v, pos, cfg, window: int | None,
-                mlp_fn=None, valid_len=None, slot=None,
-                k_scale=None, v_scale=None):
-    """Single-token decode.  x: (B, d); caches **head-major**
-    (B, KV, S_max, hd), sequence-sharded over tp.  `pos` is the
-    absolute position (RoPE); `slot` the cache index to write (ring
-    position for windowed ring buffers, defaults to pos); `valid_len`
-    the number of valid cache entries.
-    Returns (y, new_k_cache, new_v_cache).
+# TPU vector lanes: a minor dimension is laid out in whole multiples of it
+LANES = 128
 
-    Layout note (§Perf decode iteration): with the former (B, S, KV,
-    hd) layout, the score dot's batch dims (B, KV) forced XLA to
-    materialize a transposed copy of the whole per-layer cache slice
-    every token (~2x cache bytes/token/layer); head-major caches feed
-    the dot directly."""
+
+def cache_head_dim(cfg) -> int:
+    """The decode cache's head dimension: head_dim padded to whole lanes,
+    which the TPU pads to anyway when head_dim is the minor dimension."""
+    return -(-cfg.head_dim // LANES) * LANES
+
+
+def attn_decode(p, x, cache, layer, pos, cfg, window: int | None,
+                mlp_fn=None, slot=None):
+    """Single-token decode through one layer of a stage's stacked cache.
+
+    x: (B, d).  `cache` holds the stage's caches **sequence-major**, k/v
+    (r, S, KV, B, hdc) with hdc = `cache_head_dim` (the pad is zeros),
+    sequence-sharded over tp; int8 caches add k_scale/v_scale (r, S, KV,
+    B).  `layer` indexes r.  `pos` is the absolute position (RoPE); `slot`
+    the cache index to write (ring position for windowed ring buffers,
+    defaults to pos).  Returns (y, cache) with the new token written at
+    (layer, slot).
+
+    Layout note: the cache is the layer scan's carry and never leaves it.
+    The layer's slab is read in place; attention runs over its entries
+    other than `slot` (those before `pos`; for a ring, the valid entries
+    other than `slot`), with the new token's own score and value joined in
+    the same softmax.  The one-token write is then the slab's last use,
+    and with S major it is one contiguous block, so XLA updates the
+    carried buffer in place.  This is the layout XLA picks for the loop
+    when it may choose; padding hd to whole lanes makes it the default
+    layout at the jit boundary too (with an unpadded minor hd the TPU's
+    default puts S minor, and the loop then converts the whole cache on
+    entry and exit).  No (B, S, KV, hd) slab: XLA transposed it per token."""
     B, d = x.shape
-    S = cache_k.shape[2]
+    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    G = H // KV
+    S, hdc = cache["k"].shape[1], cache["k"].shape[4]
     h = rms_norm(x, p["norm1"])
     positions = jnp.broadcast_to(pos[None], (B, 1)) if pos.ndim == 0 \
         else pos[:, None]
@@ -117,57 +137,64 @@ def attn_decode(p, x, cache_k, cache_v, pos, cfg, window: int | None,
     q = rope(q, positions, cfg.rope_theta)[:, 0]
     k_new = rope(k, positions, cfg.rope_theta)[:, 0]
     v_new = v[:, 0]
-    # write the new token at `slot` (sharded dynamic-update-slice)
+
+    def pad(a):          # head_dim -> the cache's lane-padded head_dim
+        return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, hdc - hd)])
+
     posi = pos if pos.ndim == 0 else pos[0]
     sloti = posi if slot is None else slot
-    quant = cache_k.dtype == jnp.int8
+    quant = cache["k"].dtype == jnp.int8
     if quant:
         # int8 KV: per-(token, head) scales; the cache payload halves
         # (the decode bandwidth floor — §Perf roofline notes)
         ks = jnp.maximum(jnp.abs(k_new).max(-1), 1e-8).astype(F32) / 127
         vs = jnp.maximum(jnp.abs(v_new).max(-1), 1e-8).astype(F32) / 127
-        k_w = jnp.round(k_new.astype(F32) / ks[..., None])
-        v_w = jnp.round(v_new.astype(F32) / vs[..., None])
-        k_w = jnp.clip(k_w, -127, 127).astype(jnp.int8)
-        v_w = jnp.clip(v_w, -127, 127).astype(jnp.int8)
-        k_scale = jax.lax.dynamic_update_slice_in_dim(
-            k_scale, ks[:, :, None], sloti, axis=2)
-        v_scale = jax.lax.dynamic_update_slice_in_dim(
-            v_scale, vs[:, :, None], sloti, axis=2)
+        k_w = jnp.clip(jnp.round(k_new.astype(F32) / ks[..., None]),
+                       -127, 127).astype(jnp.int8)
+        v_w = jnp.clip(jnp.round(v_new.astype(F32) / vs[..., None]),
+                       -127, 127).astype(jnp.int8)
     else:
-        k_w, v_w = k_new, v_new
-    cache_k = jax.lax.dynamic_update_slice_in_dim(
-        cache_k, k_w[:, :, None].astype(cache_k.dtype), sloti, axis=2)
-    cache_v = jax.lax.dynamic_update_slice_in_dim(
-        cache_v, v_w[:, :, None].astype(cache_v.dtype), sloti, axis=2)
-    cache_k = shard(cache_k, DP, None, TP, None)
-    cache_v = shard(cache_v, DP, None, TP, None)
-    # attention over the S-sharded cache: partial softmax + all-reduce
-    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    G = H // KV
-    qg = q.reshape(B, KV, G, hd)
-    s = jnp.einsum("bhgk,bhsk->bhgs", qg.astype(F32)
-                   if quant else qg,
-                   cache_k.astype(F32 if quant else qg.dtype),
+        k_w = k_new.astype(cache["k"].dtype)
+        v_w = v_new.astype(cache["v"].dtype)
+    k_w, v_w = pad(k_w), pad(v_w)
+    slab = {n: shard(jax.lax.dynamic_index_in_dim(c, layer, 0, False),
+                     TP, None, DP, *((None,) * (c.ndim - 4)))
+            for n, c in cache.items()}
+    # scores: the old slab's (partial softmax over the S-sharded cache +
+    # all-reduce) and the new token's own
+    cdt = F32 if quant else q.dtype
+    qg = pad(q.reshape(B, KV, G, hd)).astype(cdt)
+    s = jnp.einsum("bhgk,shbk->bhgs", qg, slab["k"].astype(cdt),
                    preferred_element_type=F32) * (hd ** -0.5)
+    s_new = jnp.einsum("bhgk,bhk->bhg", qg, k_w.astype(cdt),
+                       preferred_element_type=F32) * (hd ** -0.5)
     if quant:   # fold the k scales in post-dot (no dequantized cache)
-        s = s * k_scale[:, :, None, :]
+        s = s * jnp.einsum("shb->bhs", slab["k_scale"])[:, :, None]
+        s_new = s_new * ks[:, :, None]
     s = shard(s, DP, None, None, TP)
     pk = jnp.arange(S)
-    vlen = (posi + 1) if valid_len is None else valid_len
-    mask = pk < vlen
+    mask = (pk < jnp.minimum(posi + 1, S)) & (pk != sloti)
     if window is not None:
         mask &= pk > (posi - window)
     s = jnp.where(mask[None, None, None], s, NEG_INF)
-    w = jax.nn.softmax(s, axis=-1)            # reductions over sharded S
+    m = jnp.maximum(s.max(-1), s_new)          # reductions over sharded S
+    e = jnp.exp(s - m[..., None])
+    e_new = jnp.exp(s_new - m)
+    denom = e.sum(-1) + e_new
+    w, w_new = e / denom[..., None], e_new / denom
     if quant:   # fold the v scales into the probabilities
-        wv = (w * v_scale[:, :, None, :]).astype(F32)
-        o = jnp.einsum("bhgs,bhsk->bhgk", wv, cache_v.astype(F32),
+        wv = w * jnp.einsum("shb->bhs", slab["v_scale"])[:, :, None]
+        o = jnp.einsum("bhgs,shbk->bhgk", wv, slab["v"].astype(F32),
                        preferred_element_type=F32)
+        o = o + (w_new * vs[:, :, None])[..., None] \
+            * v_w.astype(F32)[:, :, None]
     else:
-        o = jnp.einsum("bhgs,bhsk->bhgk", w.astype(cache_v.dtype),
-                       cache_v, preferred_element_type=F32)
-    o = o.reshape(B, H, hd).astype(x.dtype)
+        vdt = slab["v"].dtype
+        o = jnp.einsum("bhgs,shbk->bhgk", w.astype(vdt), slab["v"],
+                       preferred_element_type=F32)
+        o = o + w_new.astype(vdt).astype(F32)[..., None] \
+            * v_w.astype(F32)[:, :, None]
+    o = o[..., :hd].reshape(B, H, hd).astype(x.dtype)
     o = jnp.einsum("bhk,hkd->bd", o, p["wo"])
     x = x + o
     h = rms_norm(x, p["norm2"])
@@ -175,4 +202,11 @@ def attn_decode(p, x, cache_k, cache_v, pos, cfg, window: int | None,
         y = mlp_fn(h)
     else:
         y = swiglu(h, p["w_gate"], p["w_up"], p["w_down"])
-    return shard(x + y, DP, None), cache_k, cache_v, k_scale, v_scale
+    # the token write, the slab's last use: in place in the carry
+    new = {"k": k_w, "v": v_w}
+    if quant:
+        new.update(k_scale=ks, v_scale=vs)
+    cache = {n: jax.lax.dynamic_update_slice(
+        c, jnp.swapaxes(new[n], 0, 1)[None, None].astype(c.dtype),
+        (layer, sloti) + (0,) * (c.ndim - 2)) for n, c in cache.items()}
+    return shard(x + y, DP, None), cache

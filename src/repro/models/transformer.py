@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from ..distributed.sharding import (DP, EMBED_D, FSDP, SP, TP, VOCAB,
                                     shard, logical_spec)
 from .attention import (attn_block, attn_decode, attn_specs,
-                        init_attn_block)
+                        cache_head_dim, init_attn_block)
 from .common import F32, cross_entropy, rms_norm
 from .config import ModelConfig
 from .mamba2 import (init_mamba2, mamba2_mixer, mamba2_specs, mamba2_step)
@@ -266,8 +266,8 @@ def init_cache(cfg: ModelConfig, batch: int, s_max: int):
                                        conv_dim), dt)}
             else:
                 S = _cache_len(block, cfg, s_max)
-                # head-major (see attn_decode layout note)
-                kv = (repeat, batch, cfg.n_kv_heads, S, cfg.head_dim)
+                # sequence-major, hd padded (see attn_decode layout note)
+                kv = (repeat, S, cfg.n_kv_heads, batch, cache_head_dim(cfg))
                 if cfg.kv_quant:
                     # int8 payload + per-(token, head) f32 scales:
                     # halves cache bytes (the decode bandwidth floor)
@@ -305,12 +305,12 @@ def cache_specs(cache, mesh, dp_axes=("data",), tp_axes=("model",),
 
     def one(path, arr):
         name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
-        if name in ("k", "v"):          # (r, B, KV, S, hd) head-major
-            return P(None, ax(tuple(dp_axes), arr.shape[1]), None,
-                     ax(seq_axes, arr.shape[3]), None)
-        if name in ("k_scale", "v_scale"):   # (r, B, KV, S) int8 scales
-            return P(None, ax(tuple(dp_axes), arr.shape[1]), None,
-                     ax(seq_axes, arr.shape[3]))
+        if name in ("k", "v"):          # (r, S, KV, B, hdc)
+            return P(None, ax(seq_axes, arr.shape[1]), None,
+                     ax(tuple(dp_axes), arr.shape[3]), None)
+        if name in ("k_scale", "v_scale"):   # (r, S, KV, B) int8 scales
+            return P(None, ax(seq_axes, arr.shape[1]), None,
+                     ax(tuple(dp_axes), arr.shape[3]))
         if name == "ssm":               # (r, B, nh, ns, hp)
             return P(None, ax(tuple(dp_axes), arr.shape[1]),
                      ax(tuple(tp_axes), arr.shape[2]), None, None)
@@ -324,47 +324,57 @@ def cache_specs(cache, mesh, dp_axes=("data",), tp_axes=("model",),
 
 def decode_step(params, cfg: ModelConfig, cache, tokens, pos):
     """One decode step.  tokens: (B,) int32; pos: scalar int32 (current
-    length, i.e. the position being written).  Returns (logits, cache)."""
+    length, i.e. the position being written).  Returns (logits, cache).
+
+    Each stage's stacked cache rides in the layer scan's carry; the scan's
+    xs are the stage's parameters and the layer index.  A layer reads its
+    slab in place and writes back only what changed: an attention block
+    the new token (and its int8 scales) at (layer, slot), a mamba2 block
+    its (ssm, conv) state.  Nothing cache-sized is sliced out as xs or
+    restacked as ys, so with the donated cache the step updates it where
+    it lies."""
     x = jnp.take(params["embed"], tokens, axis=0)    # (B, d)
     x = shard(x, DP, None)
     new_caches = []
     for (repeat, blocks), stage_p, stage_c in zip(
             cfg.stages, params["stages"], cache):
-        def body(xc, inp):
-            lp, lc = inp
-            new_lc = {}
+        def body(carry, inp):
+            xc, sc = carry
+            lp, i = inp
+            sc = dict(sc)
             for bi, block in enumerate(blocks):
                 key = f"b{bi}"
                 p = lp.get(key)
-                c = lc[key]
+                c = sc[key]
                 if block.kind == "mamba2":
-                    xc, (ssm, conv) = mamba2_step(p, xc, (c["ssm"],
-                                                          c["conv"]), cfg)
-                    new_lc[key] = {"ssm": ssm, "conv": conv}
+                    state = [jax.lax.dynamic_index_in_dim(c[n], i, 0, False)
+                             for n in ("ssm", "conv")]
+                    xc, (ssm, conv) = mamba2_step(p, xc, state, cfg)
+                    sc[key] = {
+                        "ssm": jax.lax.dynamic_update_index_in_dim(
+                            c["ssm"], ssm, i, 0),
+                        "conv": jax.lax.dynamic_update_index_in_dim(
+                            c["conv"], conv, i, 0)}
                 else:
                     pp = params["shared"] if block.kind == "shared_attn" \
                         else p
-                    W = c["k"].shape[2]
+                    W = c["k"].shape[1]
                     if block.window and W <= block.window:
                         slot = pos % W       # ring buffer
                         eff_window = None    # whole ring is the window
                     else:
                         slot = None
                         eff_window = block.window
-                    xc, ck, cv, ks, vs = attn_decode(
-                        pp, xc, c["k"], c["v"], pos, cfg, eff_window,
+                    xc, sc[key] = attn_decode(
+                        pp, xc, c, i, pos, cfg, eff_window,
                         mlp_fn=(lambda h, p_=p: moe_ffn(
                             p_["moe"], h, cfg, dropless=True))
                         if block.kind == "moe" else None,
-                        valid_len=jnp.minimum(pos + 1, W), slot=slot,
-                        k_scale=c.get("k_scale"),
-                        v_scale=c.get("v_scale"))
-                    new_lc[key] = {"k": ck, "v": cv}
-                    if ks is not None:
-                        new_lc[key]["k_scale"] = ks
-                        new_lc[key]["v_scale"] = vs
-            return xc, new_lc
-        x, new_c = jax.lax.scan(body, x, (stage_p, stage_c), length=repeat)
+                        slot=slot)
+            return (xc, sc), None
+        (x, new_c), _ = jax.lax.scan(
+            body, (x, stage_c), (stage_p, jnp.arange(repeat)),
+            length=repeat)
         new_caches.append(new_c)
     x = rms_norm(x, params["final_norm"])
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]

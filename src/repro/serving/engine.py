@@ -22,7 +22,8 @@ from ..obs.serving import DEFAULT_SERVING_OBS
 # The parameters are an argument, never closed over: JAX embeds a
 # closed-over array in the program as a constant (gigabytes at published
 # widths).  The cache is donated, so each step and each wave's reset
-# update it in place instead of holding a second copy.
+# update it in place instead of holding a second copy: a step writes only
+# the new token (or the mamba2 state), in the cache's default layout.
 _decode = jax.jit(decode_step, static_argnums=1, donate_argnums=2)
 _zeroed = jax.jit(lambda cache: jax.tree.map(jnp.zeros_like, cache),
                   donate_argnums=0, keep_unused=True)

@@ -58,15 +58,13 @@ def test_train_step_decreases_loss(arch):
     assert losses[-1] < losses[0], (arch, losses)
 
 
-@pytest.mark.parametrize("arch", ARCH_IDS)
-def test_decode_matches_prefill(arch):
+def _decode_against_forward(arch, T, s_max):
     """Greedy decode-with-cache must agree with teacher-forced forward."""
     cfg = smoke_config(arch)
     params = init_params(jax.random.key(0), cfg)
-    T = 12
     tokens = jax.random.randint(jax.random.key(2), (BATCH, T), 0, cfg.vocab)
     ref_logits = forward(params, cfg, tokens)          # (B, T, V)
-    cache = init_cache(cfg, BATCH, 32)
+    cache = init_cache(cfg, BATCH, s_max)
     step = jax.jit(lambda c, t, p: decode_step(params, cfg, c, t, p))
     outs = []
     for t in range(T):
@@ -76,6 +74,18 @@ def test_decode_matches_prefill(arch):
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(ref_logits, np.float32),
                                rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("arch", ARCH_IDS)
+def test_decode_matches_prefill(arch):
+    _decode_against_forward(arch, T=12, s_max=32)
+
+
+@pytest.mark.parametrize("arch", ARCH_IDS)
+def test_decode_matches_prefill_at_cache_edges(arch):
+    """Decode fills the cache to its last position; the smoke configs'
+    16-token windows make ring buffers wrap twice."""
+    _decode_against_forward(arch, T=32, s_max=32)
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)

@@ -10,6 +10,7 @@ the TPU library, and every test worker imports this file.
 import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -89,3 +90,44 @@ def test_decode_step_full_width_fits_one_chip(one_chip):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert total < HBM_BYTES
+
+
+def _cache_sized(line, op, shapes):
+    m = re.search(rf"= \w+\[([\d,]*)\]\S* {op}\(", line)
+    return bool(m) and tuple(
+        int(d) for d in m.group(1).split(",") if d) in shapes
+
+
+@pytest.mark.parametrize("arch,kind,batch", [("stablelm-3b", "attn", 16),
+                                             ("mamba2-1.3b", "mamba2", 32)])
+def test_decode_step_updates_cache_in_place(one_chip, arch, kind, batch):
+    """The step as the engine compiles it keeps the donated cache where it
+    is: no copy of the stacked cache or of a layer's slab (a layout change
+    or a restacked scan output), no cache-sized dynamic-update-slice left
+    unfused (on the TPU that one rewrites the whole array), and scratch
+    below one layer's slab."""
+    cfg = dataclasses.replace(get_config(arch), stages=((2, (Block(kind),)),))
+    params = _on(one_chip, jax.eval_shape(
+        lambda k: init_params(k, cfg), jax.random.key(0)))
+    cache = _on(one_chip, jax.eval_shape(
+        lambda: init_cache(cfg, batch, 512)))
+    toks = jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = engine._decode.lower(params, cfg, cache, toks, pos).compile()
+    leaves = jax.tree.leaves(cache)
+    slab = max(a.size // a.shape[0] * a.dtype.itemsize for a in leaves)
+    assert compiled.memory_analysis().temp_size_in_bytes < slab
+    shapes = set()
+    for a in leaves:
+        shapes |= {a.shape, (1, *a.shape[1:]), a.shape[1:]}
+    copies, unfused, computation = [], [], ""
+    for line in compiled.as_text().splitlines():
+        if line[:1] not in ("", " ") and line.rstrip().endswith("{"):
+            computation = line.split()[0]      # a computation's header
+        elif _cache_sized(line, "copy", shapes):
+            copies.append(line)
+        elif (_cache_sized(line, "dynamic-update-slice", shapes)
+              and not computation.startswith("%fused")):
+            unfused.append(line)
+    assert not copies, copies
+    assert not unfused, unfused
