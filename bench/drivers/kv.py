@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.lib import models
 from bench.lib.cell import load_module
 from bench.reference import ralt as ralt_ref
 
@@ -111,7 +110,7 @@ class Driver:
     def __init__(self, cell, seed: int):
         from repro.tiering import KVTierConfig
         self.cell, self.seed = cell, seed
-        self.spec = s = models.spec(cell.config)
+        self.spec = s = cell.family.spec(cell.config)
         k = cell.config["kv_tier"]
         self.kvcfg = KVTierConfig(
             n_pages=k["n_pages"], fast_slots=k["fast_slots"],

@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.lib import models
 from bench.lib.cell import load_module
 from bench.reference import lowp
 
@@ -108,8 +107,8 @@ class Driver:
 
     def __init__(self, cell, seed: int):
         self.cell, self.seed = cell, seed
-        self.spec = models.spec(cell.config)
-        self.mcfg = models.model_config(self.spec)
+        self.spec = cell.family.spec(cell.config)
+        self.mcfg = cell.family.model_config(self.spec)
         sv = cell.config["serving"]
         self.batch, self.max_len = sv["batch"], sv["max_len"]
         self.ref = load_module("reference", cell.config["reference"],

@@ -1,7 +1,7 @@
 """Look a cell up in `BENCHMARK.json` and load what it names.
 
-Nothing here knows a particular configuration, traffic mix or metric:
-each is found by the name the cell gives it.
+Nothing here knows a particular configuration, model family, traffic mix
+or metric: each is found by the name the cell gives it.
 """
 from __future__ import annotations
 
@@ -10,6 +10,7 @@ import importlib.util
 import json
 import pathlib
 import sys
+import types
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 
@@ -20,6 +21,7 @@ class Cell:
     chips: int
     config_name: str
     config: dict            # bench/configs/<config>.json
+    family: types.ModuleType    # bench/families/<config's family>.py
     traffic_name: str
     traffic: dict           # bench/traffic/<traffic>.json
     end_to_end: list        # metric entries of BENCHMARK.json that apply
@@ -53,6 +55,7 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
     configs = {c["name"]: c for c in bench["configs"]}
     cfg_entry = configs[w["config"]]
     config = json.loads((root / cfg_entry["file"]).read_text())
+    family = load_module("families", config["family"], root)
     traffic = json.loads(_find(root, "traffic", f"{w['traffic']}.json")
                          .read_text())
     e2e = [m for m in bench["end_to_end"] if _applies(m, name, set())]
@@ -60,8 +63,9 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
     per_layer = [m for m in bench["per_layer"]
                  if _applies(m, name, e2e_names)]
     return Cell(name=name, chips=int(w["chips"]), config_name=w["config"],
-                config=config, traffic_name=w["traffic"], traffic=traffic,
-                end_to_end=e2e, per_layer=per_layer, root=root)
+                config=config, family=family, traffic_name=w["traffic"],
+                traffic=traffic, end_to_end=e2e, per_layer=per_layer,
+                root=root)
 
 
 def _find(root: pathlib.Path, kind: str, file: str) -> pathlib.Path:

@@ -16,7 +16,7 @@ import math
 import sys
 import time
 
-from . import device, models
+from . import device
 from .cell import Cell, load_module
 from .trace import TRACED_SPAN, Capture, Reduction, Stretch, load_xplane, \
     reduce
@@ -29,7 +29,7 @@ class Run:
     """What a metric reader sees."""
     cell: Cell
     seed: int
-    spec: models.Spec
+    spec: object            # the cell's family's spec(config)
     peaks: dict | None
     setup_s: float
     record: dict                # the driver's record of the window, or of
@@ -135,7 +135,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     if stretch is not None:
         red = reduce(load_xplane(stretch.path), TRACED_SPAN)
     mem = device.memory_peak_bytes(devs) if devs else 0
-    run = Run(cell=cell, seed=seed, spec=models.spec(cell.config),
+    run = Run(cell=cell, seed=seed, spec=cell.family.spec(cell.config),
               peaks=peaks, setup_s=setup_s,
               record=drv.stretch_record(stretch) if stretch else record,
               trace=red)

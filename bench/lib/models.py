@@ -1,13 +1,25 @@
-"""A configuration file as sizes: the plain `Spec` the references and the
-operation counts read, and the program's own `ModelConfig`.
+"""What a configuration file becomes: the plain `Spec` that the references
+and the operation counts read, and the program's own `ModelConfig`.
 
-Two families are known, by the file's `family` key:
+A configuration file's `family` key names a module,
+bench/families/<family>.py, found by name like a cell's driver or
+reference (`cell.load_module`: the cell's root first, then this
+checkout), so a configuration of a new family joins by new files alone.
+Such a module provides
 
-  transformer   a dense decoder: pre-norm attention and SwiGLU MLP
-                (keys as in the published config.json: hidden_size,
-                num_hidden_layers, num_attention_heads, ...)
-  mamba2        a Mamba2 (SSD) stack (d_model, n_layer, and the Mamba2
-                layer's d_state, d_conv, expand, headdim)
+  spec(cfg)                    the sizes as served: a frozen dataclass
+                               (this `Spec`, or one of the family's own),
+                               with the configuration's `departures`
+                               applied (`served`)
+  model_config(spec)           the program's ModelConfig
+  matmul_flops_per_token(spec) the operations the model needs, from its
+  mixer_flops(spec, pos)       shapes alone (bench/lib/flops.py keeps
+  sequence_flops(spec, n)      what belongs to no family)
+
+The harness and the drivers read only `family`, `name`, `vocab` and
+`dtype` of a spec.  Its other fields are read only by the family's own
+module and reference, and by a driver meant for that family (the kv
+driver's page shape).
 """
 from __future__ import annotations
 
@@ -47,52 +59,3 @@ def served(cfg: dict, key: str, published):
     holds the published value, and `departures` the program's own where
     it differs."""
     return cfg.get("departures", {}).get(key, {}).get("served", published)
-
-
-def spec(cfg: dict) -> Spec:
-    fam = cfg["family"]
-    if fam == "transformer":
-        d, h = cfg["hidden_size"], cfg["num_attention_heads"]
-        if served(cfg, "rope_pct", cfg["rope_pct"]) != 1.0:
-            raise ValueError("the program and the reference rotate every "
-                             "head dimension")
-        return Spec(
-            family=fam, name=cfg["name"], d_model=d,
-            n_layers=cfg["num_hidden_layers"], vocab=cfg["vocab_size"],
-            dtype=cfg["torch_dtype"],
-            norm_eps=served(cfg, "norm_eps", cfg["norm_eps"]),
-            tie=cfg["tie_word_embeddings"], n_heads=h,
-            n_kv_heads=cfg["num_key_value_heads"], head_dim=d // h,
-            d_ff=cfg["intermediate_size"], rope_theta=cfg["rope_theta"])
-    if fam == "mamba2":
-        a = cfg["assumed"]
-        d = cfg["d_model"]
-        di = a["expand"] * d
-        return Spec(
-            family=fam, name=cfg["name"], d_model=d,
-            n_layers=cfg["n_layer"], vocab=cfg["served_vocab_size"],
-            dtype=cfg["dtype"],
-            norm_eps=served(cfg, "norm_eps", a["norm_eps"]),
-            tie=cfg["tie_embeddings"], ssm_state=a["d_state"],
-            ssm_heads=di // a["headdim"], ssm_head_dim=a["headdim"],
-            ssm_conv=a["d_conv"], ssm_chunk=a["chunk_size"])
-    raise ValueError(f"unknown family {fam!r}")
-
-
-def model_config(s: Spec):
-    """The program's ModelConfig for these sizes."""
-    from repro.models.config import Block, ModelConfig
-    if s.family == "transformer":
-        return ModelConfig(
-            name=s.name, d_model=s.d_model, n_heads=s.n_heads,
-            n_kv_heads=s.n_kv_heads, head_dim=s.head_dim, d_ff=s.d_ff,
-            vocab=s.vocab, stages=((s.n_layers, (Block("attn"),)),),
-            rope_theta=s.rope_theta, tie_embeddings=s.tie, dtype=s.dtype)
-    return ModelConfig(
-        name=s.name, d_model=s.d_model, n_heads=0, n_kv_heads=0,
-        head_dim=0, d_ff=0, vocab=s.vocab,
-        stages=((s.n_layers, (Block("mamba2"),)),),
-        ssm_state=s.ssm_state, ssm_heads=s.ssm_heads,
-        ssm_head_dim=s.ssm_head_dim, ssm_conv=s.ssm_conv,
-        ssm_chunk=s.ssm_chunk, tie_embeddings=s.tie, dtype=s.dtype,
-        subquadratic=True)

@@ -34,7 +34,7 @@ def serving_mfu_pct(run):
     seqs = run.counters["sequences"]
     if not seqs:
         return None
-    need = sum(flops.sequence_flops(run.spec, n) for n in seqs)
+    need = sum(run.cell.family.sequence_flops(run.spec, n) for n in seqs)
     return need / (run.trace.window_s * run.peaks["bf16_flops_per_s"]) * PCT
 
 

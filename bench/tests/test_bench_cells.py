@@ -1,5 +1,7 @@
 """Whole runs of the tiny fixture cells on the CPU: the window's
-arithmetic, traced runs, and a cell defined only in new files."""
+arithmetic, traced runs, and cells defined only in new files, of a
+family the checkout knows and of one it lacks."""
+import dataclasses
 import json
 import shutil
 
@@ -209,3 +211,92 @@ def test_cell_defined_only_in_new_files(tmp_path, run_tiny):
     assert {"tokens_per_s", "setup_s"} <= set(r["metrics"])
     r = run_tiny("other.short", root=root, trace=True)
     assert r["metrics"]["waves_seen.tps"]["value"] > 0
+
+
+# ----------------------------------- a cell of a new family, new files only
+FACTOR = 4.0                # a power of two: the ratio below is exact
+
+NEW_FAMILY = f'''"""A family the checkout lacks: the transformer's
+layers, its sequences' operations counted {FACTOR} times."""
+from bench.lib.cell import load_module
+
+base = load_module("families", "transformer")
+spec, model_config = base.spec, base.model_config
+matmul_flops_per_token, mixer_flops = (base.matmul_flops_per_token,
+                                       base.mixer_flops)
+
+
+def sequence_flops(s, n_tokens):
+    return {FACTOR} * base.sequence_flops(s, n_tokens)
+'''
+
+
+def _new_family_checkout(root, family):
+    """BENCHMARK.json, a configuration of `family`, and a traffic mix,
+    under `root`; drivers, references and metrics come from the
+    checkout."""
+    for kind in ("configs", "traffic", "families"):
+        (root / "bench" / kind).mkdir(parents=True)
+    cfg = json.loads((TINY / "bench" / "configs" / "stablelm-tiny.json")
+                     .read_text())
+    cfg.update(name="hybrid-tiny", family=family)
+    (root / "bench" / "configs" / "hybrid-tiny.json").write_text(
+        json.dumps(cfg))
+    shutil.copy(TINY / "bench" / "traffic" / "tiny_batch.json",
+                root / "bench" / "traffic" / "hybrid_batch.json")
+    b = json.loads((TINY / "BENCHMARK.json").read_text())
+    b["configs"] = [{"name": "hybrid-tiny", "source": cfg["source"],
+                     "file": "bench/configs/hybrid-tiny.json",
+                     "reduced": cfg["reduced"], "why": "test"}]
+    b["workloads"] = [{"name": "hybrid.batch", "config": "hybrid-tiny",
+                       "traffic": "hybrid_batch", "chips": 1, "why": "test"}]
+    b["end_to_end"] = [dict(m, workloads=["hybrid.batch"])
+                       for m in b["end_to_end"] if m["name"] == "tokens_per_s"
+                       ] + [m for m in b["end_to_end"]
+                            if m["name"] == "setup_s"]
+    b["per_layer"] = [dict(m, workloads=["hybrid.batch"])
+                      for m in b["per_layer"] if m["name"] == "step_mfu.tps"]
+    (root / "BENCHMARK.json").write_text(json.dumps(b))
+
+
+def test_cell_of_a_new_family_defined_only_in_new_files(tmp_path, run_tiny,
+                                                        monkeypatch):
+    """A later change adds a family module with its configuration, traffic
+    mix and BENCHMARK.json entries, editing nothing: the cell is served,
+    checked and counted from its own files, its FLOPs from its family."""
+    from bench.lib import device, harness
+    from bench.lib.cell import load_module
+    root = tmp_path / "checkout"
+    _new_family_checkout(root, "fourfold")
+    (root / "bench" / "families" / "fourfold.py").write_text(NEW_FAMILY)
+    r = run_tiny("hybrid.batch", root=root)
+    assert r["correct"], r["checks"]
+    assert {"tokens_per_s", "setup_s"} <= set(r["metrics"])
+
+    runs, read = [], harness._read_metrics
+
+    def keep(run, entries):
+        runs.append(run)
+        return read(run, entries)
+
+    monkeypatch.setattr(harness, "_read_metrics", keep)
+    r = run_tiny("hybrid.batch", root=root, trace=True)
+    assert r["correct"], r["checks"]
+    (run,) = runs
+    assert run.cell.family.__file__.startswith(str(root))
+    # the CPU has no peaks: read the metric as a v5e's numbers would be
+    run = dataclasses.replace(run, peaks=device.peaks("TPU v5 lite"))
+    step_mfu = load_module("metrics", "step_mfu.tps", root).read
+    plain = dataclasses.replace(run, cell=dataclasses.replace(
+        run.cell, family=load_module("families", "transformer")))
+    assert step_mfu(plain) > 0
+    assert step_mfu(run) == FACTOR * step_mfu(plain)
+
+
+def test_cell_of_a_family_without_a_file_stops_in_load_cell(tmp_path):
+    from bench.lib.cell import load_cell
+    root = tmp_path / "checkout"
+    _new_family_checkout(root, "no_such_family")
+    with pytest.raises(FileNotFoundError,
+                       match="bench/families/no_such_family.py"):
+        load_cell("hybrid.batch", root)

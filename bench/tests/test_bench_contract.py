@@ -16,6 +16,8 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 LINE = re.compile(r"^[^\t\n]{1,200}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+FAMILY_FUNCTIONS = ("spec", "model_config", "matmul_flops_per_token",
+                    "mixer_flops", "sequence_flops")
 
 
 @pytest.fixture(params=["repo", "tiny"])
@@ -74,6 +76,8 @@ def test_every_cell_finds_its_files_by_name(bench):
         load_module("drivers", cell.traffic["driver"], root)
         load_module("traffic", cell.traffic["generator"], root)
         load_module("reference", cell.config["reference"], root)
+        for f in FAMILY_FUNCTIONS:
+            assert callable(getattr(cell.family, f))
         names = {m["name"] for m in cell.end_to_end}
         assert "setup_s" in names and len(names) >= 2
         assert cell.per_layer
@@ -102,14 +106,14 @@ def test_configs_state_their_cut(bench):
 def test_spec_serves_the_departures():
     """The file holds the published values; the references and the
     program are built with the served ones."""
-    from bench.lib import models
     cfg = json.loads((ROOT / "bench" / "configs" / "stablelm-3b.json")
                      .read_text())
+    family = load_module("families", cfg["family"])
     assert (cfg["norm_eps"], cfg["rope_pct"]) == (1e-05, 0.25)
-    assert models.spec(cfg).norm_eps == 1e-06
+    assert family.spec(cfg).norm_eps == 1e-06
     cfg["departures"].pop("rope_pct")
     with pytest.raises(ValueError, match="rotate every head dimension"):
-        models.spec(cfg)
+        family.spec(cfg)
 
 
 def test_off_a_tpu_no_result_and_nonzero_exit():

@@ -8,23 +8,29 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench.lib import models
+from bench.lib.cell import load_module
 from bench.reference import lowp, mamba2, ralt, transformer
 from bench.tests.conftest import TINY
 
 
+def config(name):
+    return json.loads((TINY / "bench" / "configs" / f"{name}.json")
+                      .read_text())
+
+
 def spec(name, **kw):
-    cfg = json.loads((TINY / "bench" / "configs" / f"{name}.json")
-                     .read_text())
-    return dataclasses.replace(models.spec(cfg), dtype="float32", **kw)
+    cfg = config(name)
+    s = load_module("families", cfg["family"], TINY).spec(cfg)
+    return dataclasses.replace(s, dtype="float32", **kw)
 
 
 def program_params(s, seed):
     from repro.models.transformer import init_params
-    mcfg = models.model_config(s)
+    cfg = config(s.name)
+    mcfg = load_module("families", cfg["family"], TINY).model_config(s)
     want = jax.eval_shape(lambda: init_params(jax.random.key(0), mcfg))
     rows = want["embed"].shape[0]
-    ref = transformer if s.family == "transformer" else mamba2
+    ref = load_module("reference", cfg["reference"], TINY)
     params = ref.make_params(s, seed, rows)
     assert jax.tree.structure(params) == jax.tree.structure(want)
     for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(want)):

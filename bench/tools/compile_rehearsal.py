@@ -20,7 +20,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
-from bench.lib import models  # noqa: E402
+from bench.lib.cell import load_module  # noqa: E402
 
 GiB = 2**30
 
@@ -41,8 +41,9 @@ def main(names):
     for name in names:
         cfg = json.loads((ROOT / "bench" / "configs" / f"{name}.json")
                          .read_text())
-        spec = models.spec(cfg)
-        mcfg = models.model_config(spec)
+        family = load_module("families", cfg["family"])
+        spec = family.spec(cfg)
+        mcfg = family.model_config(spec)
         B, S = cfg["serving"]["batch"], cfg["serving"]["max_len"]
         params = on(jax.eval_shape(
             lambda: init_params(jax.random.key(0), mcfg)))
