@@ -209,8 +209,10 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: int | None = None, q_offset: int = 0,
                     q_chunk: int = 1024, kv_chunk: int = 1024,
-                    kv_len: int | None = None):
+                    kv_len: int | None = None,
+                    scale: float | None = None):
     """q: (B, Sq, H, D); k, v: (B, Skv, KVH, D).  GQA via KV expansion.
+    `scale` multiplies the scores (default D ** -0.5).
 
     Scans kv in chunks with running log-sum-exp so peak memory is
     O(q_chunk * kv_chunk) per head instead of O(Sq * Skv), and a
@@ -233,7 +235,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
     _, Skv, KVH, _ = k.shape
     G = H // KVH
     kv_len = Skv if kv_len is None else kv_len
-    scale = D ** -0.5
+    scale = D ** -0.5 if scale is None else scale
     if G > 1:       # expand KV heads so every loop tensor is H-major
         k = jnp.repeat(k, G, axis=2)
         v = jnp.repeat(v, G, axis=2)

@@ -3,8 +3,11 @@
 Every architecture is a list of stages (repeat, blocks); parameters for
 a stage are stacked along the repeat dim and the stage runs as
 `jax.lax.scan` (small HLO => tractable 512-way SPMD compiles).  zamba2's
-shared attention block's weights live outside the scan and are closed
-over (true weight sharing).
+shared blocks' weights (params["shared"], one dict per block) live
+outside the scan and are closed over (true weight sharing), as is the
+embedding output x0 they read; each use's own weights are stacked in the
+stage like any block's.  A shared block's output t is held until the
+next mamba2 block of the same stage, whose norm input it joins.
 
 Vocab sizes are padded to a multiple of 256 so embeddings/logits shard
 over tp (standard practice; loss is computed over the padded vocab).
@@ -19,7 +22,9 @@ import jax.numpy as jnp
 from ..distributed.sharding import (DP, EMBED_D, FSDP, SP, TP, VOCAB,
                                     shard, logical_spec)
 from .attention import (attn_block, attn_decode, attn_specs,
-                        cache_head_dim, init_attn_block)
+                        cache_head_dim, init_attn_block, init_shared_block,
+                        init_shared_uses, shared_block, shared_block_decode,
+                        shared_block_specs, shared_uses_specs)
 from .common import F32, cross_entropy, rms_norm
 from .config import ModelConfig
 from .mamba2 import (init_mamba2, mamba2_mixer, mamba2_specs, mamba2_step)
@@ -36,8 +41,8 @@ def padded_vocab(cfg: ModelConfig) -> int:
 def _init_block(key, cfg, block, n_copies):
     if block.kind == "attn":
         return init_attn_block(key, cfg, cfg.d_ff, n_copies)
-    if block.kind == "shared_attn":
-        return None  # lives in params["shared"]
+    if block.kind == "shared_attn":   # the block is in params["shared"]
+        return init_shared_uses(key, cfg, n_copies)
     if block.kind == "moe":
         k1, k2 = jax.random.split(key)
         p = init_attn_block(k1, cfg, 1, n_copies)
@@ -65,16 +70,14 @@ def init_params(key, cfg: ModelConfig):
     if not cfg.tie_embeddings:
         params["lm_head"] = (jax.random.normal(keys[1], (d, V), F32)
                              * d ** -0.5).astype(dt)
-    needs_shared = any(b.kind == "shared_attn"
-                       for _, blocks in cfg.stages for b in blocks)
-    if needs_shared:
-        params["shared"] = init_attn_block(
-            keys[2], cfg, cfg.shared_attn_d_ff, None)
+    if cfg.n_mem_blocks:
+        params["shared"] = [
+            init_shared_block(k, cfg)
+            for k in jax.random.split(keys[2], cfg.n_mem_blocks)]
     for si, (repeat, blocks) in enumerate(cfg.stages):
         bkeys = jax.random.split(keys[3 + si], len(blocks))
         stage = {f"b{bi}": _init_block(bkeys[bi], cfg, blocks[bi], repeat)
-                 for bi in range(len(blocks))
-                 if blocks[bi].kind != "shared_attn"}
+                 for bi in range(len(blocks))}
         params["stages"].append(stage)
     return params
 
@@ -83,7 +86,7 @@ def _block_specs(cfg, block, stacked=True, moe_ff_sharded=False):
     if block.kind == "attn":
         return attn_specs(stacked)
     if block.kind == "shared_attn":
-        return None
+        return shared_uses_specs()
     if block.kind == "moe":
         s = attn_specs(stacked)
         for w in ("w_gate", "w_up", "w_down"):
@@ -91,7 +94,7 @@ def _block_specs(cfg, block, stacked=True, moe_ff_sharded=False):
         s["moe"] = moe_specs(stacked, ff_sharded=moe_ff_sharded)
         return s
     if block.kind == "mamba2":
-        return mamba2_specs(stacked)
+        return mamba2_specs(stacked, cfg.ssm_conv_bias)
     raise ValueError(block.kind)
 
 
@@ -104,16 +107,14 @@ def logical_param_specs(cfg: ModelConfig, moe_ff_sharded: bool = False):
     }
     if not cfg.tie_embeddings:
         specs["lm_head"] = (EMBED_D, VOCAB)
-    needs_shared = any(b.kind == "shared_attn"
-                       for _, blocks in cfg.stages for b in blocks)
-    if needs_shared:
-        specs["shared"] = attn_specs(False)
+    if cfg.n_mem_blocks:
+        specs["shared"] = [shared_block_specs()
+                           for _ in range(cfg.n_mem_blocks)]
     for repeat, blocks in cfg.stages:
         specs["stages"].append(
             {f"b{bi}": _block_specs(cfg, blocks[bi],
                                     moe_ff_sharded=moe_ff_sharded)
-             for bi in range(len(blocks))
-             if blocks[bi].kind != "shared_attn"})
+             for bi in range(len(blocks))})
     return specs
 
 
@@ -161,23 +162,15 @@ def param_specs(params, cfg: ModelConfig, mesh, dp_axes=("data",),
 # ----------------------------------------------------------------------
 # forward (train / prefill)
 # ----------------------------------------------------------------------
-def _apply_block(p, shared_p, x, cfg, block, collect_cache):
+def _apply_block(p, x, cfg, block, collect_cache):
     if block.kind == "attn":
         y, kv = attn_block(p, x, cfg, block.window)
-        cache = {"k": kv[0], "v": kv[1]} if collect_cache else None
-        return y, cache
-    if block.kind == "shared_attn":
-        y, kv = attn_block(shared_p, x, cfg, block.window)
         cache = {"k": kv[0], "v": kv[1]} if collect_cache else None
         return y, cache
     if block.kind == "moe":
         y, kv = attn_block(p, x, cfg, block.window,
                            mlp_fn=lambda h: moe_ffn(p["moe"], h, cfg))
         cache = {"k": kv[0], "v": kv[1]} if collect_cache else None
-        return y, cache
-    if block.kind == "mamba2":
-        y, (ssm, conv) = mamba2_mixer(p, x, cfg)
-        cache = {"ssm": ssm, "conv": conv} if collect_cache else None
         return y, cache
     raise ValueError(block.kind)
 
@@ -191,14 +184,24 @@ def forward_hidden(params, cfg: ModelConfig, tokens, *, frontend_emb=None,
         x = jnp.concatenate(
             [frontend_emb.astype(x.dtype), x[:, P_:]], axis=1)
     x = shard(x, DP, SP, None)   # SP: residual stream sequence-sharded
+    x0 = x                       # what zamba2's shared blocks read
     caches = []
     for (repeat, blocks), stage_p in zip(cfg.stages, params["stages"]):
         def body(xc, lp):
             new_cache = {}
+            t = None             # a shared block's output, for the next mamba2
             for bi, block in enumerate(blocks):
                 p = lp.get(f"b{bi}")
-                xc, c = _apply_block(p, params["shared"], xc, cfg, block,
-                                     return_cache)
+                if block.kind == "shared_attn":
+                    t, kv = shared_block(params["shared"][block.mem], p,
+                                         xc, x0, cfg)
+                    c = {"k": kv[0], "v": kv[1]} if return_cache else None
+                elif block.kind == "mamba2":
+                    xc, (ssm, conv) = mamba2_mixer(p, xc, cfg, extra=t)
+                    t = None
+                    c = {"ssm": ssm, "conv": conv} if return_cache else None
+                else:
+                    xc, c = _apply_block(p, xc, cfg, block, return_cache)
                 if c is not None:
                     new_cache[f"b{bi}"] = c
             return xc, (new_cache or None)
@@ -206,7 +209,7 @@ def forward_hidden(params, cfg: ModelConfig, tokens, *, frontend_emb=None,
             body = jax.checkpoint(body)
         x, stage_cache = jax.lax.scan(body, x, stage_p, length=repeat)
         caches.append(stage_cache)
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return (x, caches) if return_cache else x
 
 
@@ -259,11 +262,10 @@ def init_cache(cfg: ModelConfig, batch: int, s_max: int):
         for bi, block in enumerate(blocks):
             if block.kind == "mamba2":
                 nh, hp, ns = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
-                conv_dim = cfg.d_inner + 2 * ns
                 stage[f"b{bi}"] = {
                     "ssm": jnp.zeros((repeat, batch, nh, ns, hp), F32),
                     "conv": jnp.zeros((repeat, batch, cfg.ssm_conv - 1,
-                                       conv_dim), dt)}
+                                       cfg.ssm_conv_dim), dt)}
             else:
                 S = _cache_len(block, cfg, s_max)
                 # sequence-major, hd padded (see attn_decode layout note)
@@ -329,12 +331,15 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, pos):
     Each stage's stacked cache rides in the layer scan's carry; the scan's
     xs are the stage's parameters and the layer index.  A layer reads its
     slab in place and writes back only what changed: an attention block
-    the new token (and its int8 scales) at (layer, slot), a mamba2 block
-    its (ssm, conv) state.  Nothing cache-sized is sliced out as xs or
-    restacked as ys, so with the donated cache the step updates it where
-    it lies."""
+    (a shared block's use too) the new token (and its int8 scales) at
+    (layer, slot), a mamba2 block its (ssm, conv) state.  Nothing
+    cache-sized is sliced out as xs or restacked as ys, so with the
+    donated cache the step updates it where it lies.  Each shared-block
+    use and each mamba2 layer is a `jax.named_scope` (`shared_block`,
+    `mamba2`), which groups its ops in a device profile."""
     x = jnp.take(params["embed"], tokens, axis=0)    # (B, d)
     x = shard(x, DP, None)
+    x0 = x                       # what zamba2's shared blocks read
     new_caches = []
     for (repeat, blocks), stage_p, stage_c in zip(
             cfg.stages, params["stages"], cache):
@@ -342,22 +347,30 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, pos):
             xc, sc = carry
             lp, i = inp
             sc = dict(sc)
+            t = None             # a shared block's output, for the next mamba2
             for bi, block in enumerate(blocks):
                 key = f"b{bi}"
                 p = lp.get(key)
                 c = sc[key]
-                if block.kind == "mamba2":
-                    state = [jax.lax.dynamic_index_in_dim(c[n], i, 0, False)
-                             for n in ("ssm", "conv")]
-                    xc, (ssm, conv) = mamba2_step(p, xc, state, cfg)
-                    sc[key] = {
-                        "ssm": jax.lax.dynamic_update_index_in_dim(
-                            c["ssm"], ssm, i, 0),
-                        "conv": jax.lax.dynamic_update_index_in_dim(
-                            c["conv"], conv, i, 0)}
+                if block.kind == "shared_attn":
+                    with jax.named_scope("shared_block"):
+                        t, sc[key] = shared_block_decode(
+                            params["shared"][block.mem], p, xc, x0, c, i,
+                            pos, cfg)
+                elif block.kind == "mamba2":
+                    with jax.named_scope("mamba2"):
+                        state = [jax.lax.dynamic_index_in_dim(c[n], i, 0,
+                                                              False)
+                                 for n in ("ssm", "conv")]
+                        xc, (ssm, conv) = mamba2_step(p, xc, state, cfg,
+                                                      extra=t)
+                        t = None
+                        sc[key] = {
+                            "ssm": jax.lax.dynamic_update_index_in_dim(
+                                c["ssm"], ssm, i, 0),
+                            "conv": jax.lax.dynamic_update_index_in_dim(
+                                c["conv"], conv, i, 0)}
                 else:
-                    pp = params["shared"] if block.kind == "shared_attn" \
-                        else p
                     W = c["k"].shape[1]
                     if block.window and W <= block.window:
                         slot = pos % W       # ring buffer
@@ -366,7 +379,7 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, pos):
                         slot = None
                         eff_window = block.window
                     xc, sc[key] = attn_decode(
-                        pp, xc, c, i, pos, cfg, eff_window,
+                        p, xc, c, i, pos, cfg, eff_window,
                         mlp_fn=(lambda h, p_=p: moe_ffn(
                             p_["moe"], h, cfg, dropless=True))
                         if block.kind == "moe" else None,
@@ -376,7 +389,7 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, pos):
             body, (x, stage_c), (stage_p, jnp.arange(repeat)),
             length=repeat)
         new_caches.append(new_c)
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = jnp.einsum("bd,dv->bv", x, head)
     return shard(logits, DP, TP), new_caches

@@ -29,6 +29,18 @@ _zeroed = jax.jit(lambda cache: jax.tree.map(jnp.zeros_like, cache),
                   donate_argnums=0, keep_unused=True)
 
 
+def cache_bytes(cache) -> dict:
+    """Bytes of the decode cache's two kinds of state: attention K/V
+    (with int8 scales) and the mamba2 blocks' recurrent (ssm, conv)
+    state."""
+    out = {"kv_cache_bytes": 0, "ssm_state_bytes": 0}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(cache):
+        kind = "ssm_state_bytes" if path[-1].key in ("ssm", "conv") \
+            else "kv_cache_bytes"
+        out[kind] += leaf.size * leaf.dtype.itemsize
+    return out
+
+
 @dataclasses.dataclass
 class Request:
     rid: int
@@ -51,6 +63,10 @@ class ServeEngine:
         self.params = params if params is not None else init_params(
             jax.random.key(seed), cfg)
         self.cache = init_cache(cfg, batch, max_len)
+        self.cache_bytes = cache_bytes(self.cache)
+        if self._obs.poll():
+            self._obs.tracer.counter(self._obs_track, "cache",
+                                     self.cache_bytes)
         self.slots: list = [None] * batch
         self.pos = 0                    # shared position (lockstep)
         self.queue: list = []
@@ -125,6 +141,7 @@ class ServeEngine:
                                {"assigned": assigned,
                                 "queued": len(self.queue)})
                 tr.begin(track, "engine/reset")
+                tr.counter(track, "cache", self.cache_bytes)
             wave_prompt = max(len(r.prompt) for r in live)
             wave_new = max(r.max_new for r in live)
             self.reset()
@@ -142,6 +159,8 @@ class ServeEngine:
                     tr.begin(track, "engine/step",
                              {"wave": self.waves, "step": t})
                     tr.begin(track, "engine/feed")
+                    live_n = sum(r is not None and not r.done
+                                 for r in self.slots)
                 for i, r in enumerate(self.slots):
                     if r is None:
                         continue
@@ -171,7 +190,7 @@ class ServeEngine:
                             r.done = True
                 if rec:
                     tr.end(track, "engine/emit")
-                    tr.end(track, "engine/step")
+                    tr.end(track, "engine/step", {"live": live_n})
                 max_steps -= 1
                 self.steps_used += 1
                 if max_steps <= 0:
